@@ -86,6 +86,23 @@ def _aggregate_rows(runs):
             for n in order]
 
 
+def _cpu_child(module: str, marker: str):
+    """Run `python -m <module> --dist` — a measurement on forced host
+    devices — pinned to the CPU backend (a parent that has touched JAX
+    holds the chip), and return the JSON rows after `marker`.  A child
+    that fails, or prints no rows, fails the run."""
+    import subprocess
+    r = subprocess.run(
+        [sys.executable, "-m", module, "--dist"], capture_output=True,
+        text=True, cwd=_REPO, timeout=1800,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    for line in r.stdout.splitlines():
+        if r.returncode == 0 and line.startswith(marker):
+            return json.loads(line[len(marker):])
+    sys.exit(f"[{module}] measurement subprocess FAILED "
+             f"(rc={r.returncode}):\n{r.stdout[-2000:]}{r.stderr[-2000:]}")
+
+
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--scale", type=int, default=1,
@@ -133,6 +150,8 @@ def main() -> None:
         _REPO, "BENCH_recovery.json"),
         help="recovery artifact path ('' disables)")
     args = ap.parse_args()
+    from repro import compile_cache
+    compile_cache.enable()
     sections = args.sections.split(",")
     if args.check and not {"fig3", "dist", "skew", "serve",
                            "faults", "outofcore",
@@ -286,7 +305,6 @@ def main() -> None:
         print()
 
     if "dispatch" in sections:
-        import subprocess
         from benchmarks import dispatch_bench
         print("[dispatch] run() per-call overhead, eager vs whole-program "
               "(DESIGN.md §9)")
@@ -295,21 +313,11 @@ def main() -> None:
         print()
         print("[dispatch] distributed round fusion on vs off "
               "(fresh subprocess, forced host devices)")
-        r = subprocess.run(
-            [sys.executable, "-m", "benchmarks.dispatch_bench", "--dist"],
-            capture_output=True, text=True, cwd=_REPO, timeout=1800)
-        drows = None
-        for line in r.stdout.splitlines():
-            if line.startswith(dispatch_bench._DIST_MARKER):
-                drows = json.loads(line[len(dispatch_bench._DIST_MARKER):])
-        if drows is None:
-            print("[dispatch] distributed half FAILED:\n"
-                  + r.stdout[-2000:] + r.stderr[-2000:])
-            check_failed = True
-        else:
-            print(json.dumps(drows, indent=1))
+        drows = _cpu_child("benchmarks.dispatch_bench",
+                           dispatch_bench._DIST_MARKER)
+        print(json.dumps(drows, indent=1))
         print()
-        if args.dispatch_json_out and drows is not None:
+        if args.dispatch_json_out:
             with open(args.dispatch_json_out, "w") as f:
                 json.dump({"section": "dispatch", "unit": "us/ms per call",
                            "single_device": srows, "distributed": drows},
@@ -399,31 +407,20 @@ def main() -> None:
             check_failed = True
 
     if "recovery" in sections:
-        import subprocess
         from benchmarks import recovery_bench
         print("[recovery] lineage shard recovery vs fault-free vs "
               "lineage-off restart, + speculative stragglers "
               "(DESIGN.md §13; fresh subprocess, forced host devices)")
-        r = subprocess.run(
-            [sys.executable, "-m", "benchmarks.recovery_bench", "--dist"],
-            capture_output=True, text=True, cwd=_REPO, timeout=1800)
-        rrows = None
-        for line in r.stdout.splitlines():
-            if line.startswith(recovery_bench._MARKER):
-                rrows = json.loads(line[len(recovery_bench._MARKER):])
-        if rrows is None:
-            print("[recovery] measurement subprocess FAILED:\n"
-                  + r.stdout[-2000:] + r.stderr[-2000:])
+        rrows = _cpu_child("benchmarks.recovery_bench",
+                           recovery_bench._MARKER)
+        recovery_bench.print_rows(rrows)
+        print()
+        if args.recovery_json_out:
+            with open(args.recovery_json_out, "w") as f:
+                json.dump(recovery_bench.to_json(rrows), f, indent=1)
+            print(f"[recovery] wrote {args.recovery_json_out}")
+        if args.check and recovery_bench.check_rows(rrows):
             check_failed = True
-        else:
-            recovery_bench.print_rows(rrows)
-            print()
-            if args.recovery_json_out:
-                with open(args.recovery_json_out, "w") as f:
-                    json.dump(recovery_bench.to_json(rrows), f, indent=1)
-                print(f"[recovery] wrote {args.recovery_json_out}")
-            if args.check and recovery_bench.check_rows(rrows):
-                check_failed = True
 
     if check_failed:
         sys.exit(1)

@@ -1,6 +1,7 @@
 """Distributed data-analytics example: PageRank and KMeans written as
-imperative loops, compiled by DIABLO-JAX, and executed over an 8-device
-mesh with the paper's operator mapping — sharded bags AND, via the
+imperative loops, compiled by DIABLO-JAX, and executed over a mesh of
+every device — the chips of a TPU host, or 8 forced host devices on the
+CPU — with the paper's operator mapping — sharded bags AND, via the
 distribution-analysis pass (DESIGN.md §6), sharded dense arrays: the rank
 vectors are ONED_ROW row blocks, not replicas.
 
@@ -14,8 +15,10 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
+import jax
 import numpy as np
 
+from repro import compile_cache
 from repro.core import compile_program
 from repro.core.dist_analysis import Dist
 from repro.core.distributed import compile_distributed
@@ -24,7 +27,8 @@ from repro.launch.mesh import make_test_mesh
 
 
 def main():
-    mesh = make_test_mesh((8,), ("data",))
+    compile_cache.enable()
+    mesh = make_test_mesh((len(jax.devices()),), ("data",))
     rng = np.random.default_rng(0)
 
     # ---- PageRank over a random graph ----

@@ -10,6 +10,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 import numpy as np
 
+from repro import compile_cache
 from repro.core import (RejectionError, compile_program, dim, loop_program,
                         map_, matrix, vector)
 
@@ -32,6 +33,7 @@ def grouped_sum(K: vector, V: vector, C: map_, n: dim):
 
 
 def main():
+    compile_cache.enable()
     print("== source (parsed loop language) ==")
     print(matmul.program.pretty())
     cp = compile_program(matmul)

@@ -15,6 +15,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 import numpy as np
 
+from repro import compile_cache
 from repro.core import compile_program
 from repro.core.programs import group_by, kmeans_step, pagerank
 from repro.serve import PlanServer
@@ -55,6 +56,7 @@ async def client(server: PlanServer, i: int, n_requests: int):
 
 
 def main():
+    compile_cache.enable()
     print("compiling the workload programs...")
     server = PlanServer({
         "pagerank": compile_program(pagerank),

@@ -29,8 +29,7 @@ import numpy as np
 
 
 def _flatten(tree):
-    from repro.compat import tree_flatten_with_path
-    leaves = tree_flatten_with_path(tree)[0]
+    leaves = jax.tree.flatten_with_path(tree)[0]
     out = {}
     for path, leaf in leaves:
         key = "/".join(str(getattr(p, "key", getattr(p, "idx", p)))
@@ -40,8 +39,7 @@ def _flatten(tree):
 
 
 def _unflatten_like(template, flat: dict):
-    from repro.compat import tree_flatten_with_path
-    leaves, treedef = tree_flatten_with_path(template)
+    leaves, treedef = jax.tree.flatten_with_path(template)
     vals = []
     for path, leaf in leaves:
         key = "/".join(str(getattr(p, "key", getattr(p, "idx", p)))

@@ -103,7 +103,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ..compat import shard_map
 from . import faults as F
 from . import plan
 from .dist_analysis import (Dist, aligned_reads, leading_key_var,
@@ -369,9 +368,9 @@ class DistributedProgram:
                     shard = shard * self.mesh.shape[a] + \
                         jax.lax.axis_index(a)
                 return self._rebalance_local(x, shard, _lim)
-            fn = jax.jit(shard_map(local_fn, mesh=self.mesh,
-                                   in_specs=(P(self.dp),),
-                                   out_specs=P(self.dp)))
+            fn = jax.jit(jax.shard_map(local_fn, mesh=self.mesh,
+                                       in_specs=(P(self.dp),),
+                                       out_specs=P(self.dp)))
             self._round_cache[cache_key] = fn
             self._round_traces += 1
         else:
@@ -773,9 +772,15 @@ class DistributedProgram:
                         _exch.get(p.dest, "psum_scatter")))
             return tuple(outs)
 
-        fn = jax.jit(shard_map(local_fn, mesh=self.mesh,
-                               in_specs=tuple(in_specs),
-                               out_specs=out_specs))
+        # shard_map's replication check stays on unless a Pallas kernel
+        # may run here in interpret mode (off a TPU): the interpreted body
+        # mixes unvarying grid indices with varying operands, which the
+        # check refuses.  Compiled for a TPU, the kernel passes it.
+        from ..kernels import ops as kops
+        check = not (kops._interp() and cp.selector.may_pick_pallas())
+        fn = jax.jit(jax.shard_map(local_fn, mesh=self.mesh,
+                                   in_specs=tuple(in_specs),
+                                   out_specs=out_specs, check_vma=check))
         self._round_cache[cache_key] = fn
         self._round_traces += 1
         # traces: executor notes decisions
@@ -1191,9 +1196,9 @@ class DistributedProgram:
             e2.update(dict(zip(carry_names, out)))
             return tuple(e2[d] for d in dests_order)
 
-        fn = jax.jit(shard_map(local_fn, mesh=self.mesh,
-                               in_specs=tuple(in_specs),
-                               out_specs=out_specs, check_rep=False))
+        fn = jax.jit(jax.shard_map(local_fn, mesh=self.mesh,
+                                   in_specs=tuple(in_specs),
+                                   out_specs=out_specs, check_vma=False))
         try:
             # traces: executor notes decisions
             results = self._call_round(fn, args, "dist.fused_compile",
@@ -1457,6 +1462,9 @@ class DistributedProgram:
         for node in nodes:
             if isinstance(node, plan.SeqLoop):
                 out.append(f"{pre}{node.describe()}")
+                if id(node) in self._fused_bail:
+                    out.append(f"{pre}    bailed: not one fused "
+                               f"shard_map round")
                 strat = self._strategy.get(id(node))
                 if strat is not None:
                     out.append(f"{pre}    loop: {strat}")
@@ -1464,6 +1472,9 @@ class DistributedProgram:
                 continue
             if isinstance(node, plan.FusedRound):
                 out.append(f"{pre}{node.describe()}")
+                if id(node) in self._fused_bail:
+                    out.append(f"{pre}    bailed: ran as per-member "
+                               f"rounds")
                 strat = self._strategy.get(id(node))
                 if strat is not None:
                     out.append(f"{pre}    round: {strat}")

@@ -62,6 +62,7 @@ import jax.numpy as jnp
 from . import faults as F
 from . import plan as P
 from .analysis import check as check_restrictions
+from ..precision import xla_f32_dot
 from .comprehension import Get, pretty
 from .loop_ast import (BinOp, Call, Const, Program, RejectionError, UnOp,
                        Var)
@@ -800,6 +801,7 @@ class PlanExecutor:
             oh = (ids[:, None] == jnp.arange(num)[None, :]).astype(acc)
             return jax.lax.dot_general(
                 vals.astype(acc)[None, :], oh, (((1,), (0,)), ((), ())),
+                precision=xla_f32_dot(),
                 preferred_element_type=acc)[0]
         if backend == "pallas":
             from ..kernels import ops as kops
@@ -1013,7 +1015,8 @@ class PlanExecutor:
             used.update(faxes)
         out_axes = [a for a in key_axes if a in used]
         out_spec = "".join(letters[a] for a in out_axes)
-        res = jnp.einsum(",".join(specs) + "->" + out_spec, *operands)
+        res = jnp.einsum(",".join(specs) + "->" + out_spec, *operands,
+                         precision=xla_f32_dot())
         if tuple(out_axes) != tuple(key_axes):
             res = jnp.reshape(
                 res, [ax.extent[a] if a in used else 1 for a in key_axes])
@@ -1706,7 +1709,7 @@ class CompiledProgram:
         return self._bag_row_aligned
 
     def batched_call(self, key, static: dict, arrays: dict, lengths: dict,
-                     limit_bags=(), limit_arrays=()):
+                     limit_bags=(), limit_arrays=(), salts=None):
         """Run the whole-program trace vmapped over a leading request
         axis: `arrays` maps every non-dim param to a [B, ...]-stacked
         value (bags as tuples of [B, N] columns), `lengths` maps each
@@ -1718,10 +1721,11 @@ class CompiledProgram:
         sets); entries live in the SAME `_whole_cache` as single-request
         signatures and count toward trace_count/cache_hits.  Mutated
         destinations are donated — callers pass freshly device_put
-        buffers and must not reuse them.  Hot-key salting stays off on
-        this path (keys are tracers under vmap; the probe needs concrete
-        data).  Raises on trace failure — the serving layer falls back to
-        sequential run() per request."""
+        buffers and must not reuse them.  `salts` (dest → hot-key salt
+        factor) is decided by the caller from concrete host data, the
+        same probe a solo run() takes, and must be part of `key` — keys
+        are tracers under vmap.  Raises on trace failure — the serving
+        layer falls back to sequential run() per request."""
         ck = ("batched", key)
         donated = {n: v for n, v in arrays.items()
                    if n in self._donate_names}
@@ -1730,6 +1734,7 @@ class CompiledProgram:
         if ent is None:
             outs = tuple(self.program.outputs)
             lb, la = tuple(limit_bags), tuple(limit_arrays)
+            salts = dict(salts or {})
 
             def traced(dnt, kpt, lens, _static=dict(static)):
                 def one(d, k_, l):
@@ -1738,7 +1743,7 @@ class CompiledProgram:
                     e.update(k_)
                     ctx = ExecContext(
                         bag_limits={n: l[n] for n in lb},
-                        array_limits={n: l[n] for n in la})
+                        array_limits={n: l[n] for n in la}, salts=salts)
                     self.executor.execute(self.plan, e, ctx)
                     return {n: e[n] for n in outs}
                 return jax.vmap(one)(dnt, kpt, lens)

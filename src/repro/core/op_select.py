@@ -55,6 +55,8 @@ import time
 from dataclasses import dataclass
 from typing import Optional
 
+import numpy as np
+
 # candidate sets per monoid ⊕ (correctness, not preference: onehot only
 # sums; sort covers every monoid via jax.ops.segment_*; pallas does + via
 # the MXU dot and min/max via the one-hot select path)
@@ -289,8 +291,22 @@ class OpSelector:
             self._platform = jax.default_backend()
         return self._platform
 
+    def may_pick_pallas(self) -> bool:
+        """Whether a Pallas backend can be chosen: by the TPU's cost row,
+        a pin, or a cached decision (the CPU's cost row prices the
+        kernels' interpret mode out)."""
+        return (self.platform == "tpu"
+                or (self.forced or "").startswith("pallas")
+                or any(str(v.get("backend", "")).startswith("pallas")
+                       for v in self._cache.values()))
+
     def _costs(self) -> dict:
-        return _COSTS.get(self.platform, _COSTS["cpu"])
+        try:
+            return _COSTS[self.platform]
+        except KeyError:
+            raise ValueError(
+                f"op_select has no cost table for platform "
+                f"{self.platform!r} (known: {sorted(_COSTS)})") from None
 
     # ---- cache ----
     def load(self, path: str) -> None:
@@ -332,6 +348,11 @@ class OpSelector:
                        dest_dist: str = "REP",
                        candidates: Optional[tuple] = None) -> Decision:
         cands = candidates or SEGMENT_CANDIDATES.get(op, ("scatter",))
+        if op == "+" and self.platform == "tpu" \
+                and np.issubdtype(np.dtype(str(dtype)), np.integer):
+            # the MXU has no int32 dot: Mosaic refuses the kernel's
+            # exact-int sum, so integer sums take an XLA backend
+            cands = tuple(b for b in cands if b != "pallas")
         if self.forced is not None and self.forced in cands:
             return Decision(self.forced, "forced")
         # a forced backend the candidate set does not admit (e.g.

@@ -1,8 +1,10 @@
 """Public jit'd wrappers over the Pallas kernels.
 
-`interpret` defaults to True off-TPU (the container is CPU-only; Pallas
-kernels are authored for TPU and validated in interpret mode against the
-pure-jnp oracles in *_ref.py)."""
+`interpret` defaults to True off-TPU: the CPU test path, where the Pallas
+kernels (authored for TPU) are validated in interpret mode against the
+pure-jnp oracles in *_ref.py.  On a TPU the kernels always compile with
+Mosaic — asking for interpret mode there is an error, never a silent
+slow path."""
 from __future__ import annotations
 
 import jax
@@ -18,26 +20,28 @@ def _interp() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def segment_sum(ids, values, num_segments: int, **kw):
+def _mode(kw: dict) -> dict:
     kw.setdefault("interpret", _interp())
-    return _segsum(ids, values, num_segments, **kw)
+    if kw["interpret"] and not _interp():
+        raise ValueError("Pallas interpret mode requested on a TPU backend")
+    return kw
+
+
+def segment_sum(ids, values, num_segments: int, **kw):
+    return _segsum(ids, values, num_segments, **_mode(kw))
 
 
 def segment_reduce(ids, values, num_segments: int, *, op: str = "+", **kw):
-    kw.setdefault("interpret", _interp())
-    return _segred(ids, values, num_segments, op=op, **kw)
+    return _segred(ids, values, num_segments, op=op, **_mode(kw))
 
 
 def tile_matmul(a, b, tile_mask=None, **kw):
-    kw.setdefault("interpret", _interp())
-    return _tilemm(a, b, tile_mask=tile_mask, **kw)
+    return _tilemm(a, b, tile_mask=tile_mask, **_mode(kw))
 
 
 def flash_attention(q, k, v, **kw):
-    kw.setdefault("interpret", _interp())
-    return _flash(q, k, v, **kw)
+    return _flash(q, k, v, **_mode(kw))
 
 
 def selective_scan(a, bx, c, **kw):
-    kw.setdefault("interpret", _interp())
-    return _selscan(a, bx, c, **kw)
+    return _selscan(a, bx, c, **_mode(kw))

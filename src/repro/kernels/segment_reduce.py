@@ -1,21 +1,26 @@
 """Pallas TPU kernel: segment-⊕ via one-hot MXU matmul / one-hot select.
 
 This is the TPU-native form of the paper's group-by-⊕: instead of a shuffle
-(Spark) or a scatter (GPU), each [bn] block of segment ids becomes a
-[bn, bk] one-hot matrix.  For ⊕ = + the one-hot multiplies the [bn, bd]
-value block on the MXU — group-by as matrix multiplication; for ⊕ = min/max
-the one-hot SELECTS into a [bn, bk, bd] identity-filled block that reduces
-over rows on the VPU (size bn·bk·bd·4 bytes must fit VMEM — shrink the
-blocks for large bd).  Out-of-range ids ([num_segments, ∞) and negatives)
-contribute nothing (drop semantics, matching the ◁ merge): padded rows get
-id = Kp which no k-block matches, and ids in [num_segments, Kp) land in
-output rows that are sliced off.
+(Spark) or a scatter (GPU), each block of bn segment ids becomes a
+[bk, bn] one-hot matrix (segments on sublanes, rows on lanes).  For ⊕ = +
+the one-hot contracts with the [bd, bn] value block on the MXU — group-by
+as matrix multiplication; for ⊕ = min/max the one-hot SELECTS the value
+row into an identity-filled [bk, bn] block that reduces over lanes on the
+VPU.  Out-of-range ids ([num_segments, ∞) and negatives) contribute
+nothing (drop semantics, matching the ◁ merge): padded rows get id = Kp
+which no k-block matches, and ids in [num_segments, Kp) land in output
+rows that are sliced off.
+
+Layout: rows run along lanes everywhere — ids as [1, N], values
+transposed to [D, N], the output as [D, K] — so every block is lane-dense
+and the 1-D id column needs no sublane relayout inside the kernel.
 
 Values may be [N] (returns [num_segments]) or [N, D] (returns
-[num_segments, D]).  Integer values accumulate on an EXACT integer path
-(int32 one-hot × int32 values with preferred_element_type=int32 — no fp32
-rounding); floating values accumulate in float32.  The returned dtype is
-the accumulator's (int32 / float32).
+[num_segments, D]).  Floating values accumulate in float32 at full f32
+precision.  Integer values accumulate EXACTLY in int32 (an int32 one-hot
+dot): v5e's MXU has no int32 dot, so Mosaic refuses integer `+` and
+op_select never picks this backend for it on a TPU; integer min/max only
+select and compile.  The returned dtype is the accumulator's.
 
 Grid: (K/bk, D/bd, N/bn), N innermost so each output tile accumulates
 across value blocks in VMEM.
@@ -30,7 +35,10 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ..precision import F32_DOT
+
 _IDENTITY = {"+": 0.0, "min": np.inf, "max": -np.inf}
+_LANES = 128
 
 
 def _int_identity(op: str) -> int:
@@ -40,7 +48,11 @@ def _int_identity(op: str) -> int:
     return big if op == "min" else -big
 
 
-def _kernel(ids_ref, val_ref, out_ref, *, bk: int, op: str, acc):
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+def _kernel(ids_ref, val_ref, out_ref, *, bk: int, bd: int, op: str, acc):
     k = pl.program_id(0)
     n = pl.program_id(2)
     ident = jnp.asarray(_int_identity(op) if acc == jnp.int32
@@ -50,33 +62,42 @@ def _kernel(ids_ref, val_ref, out_ref, *, bk: int, op: str, acc):
     def _init():
         out_ref[...] = jnp.full_like(out_ref, ident)
 
-    ids = ids_ref[...]                                    # [bn]
-    vals = val_ref[...].astype(acc)                       # [bn, bd]
-    seg0 = k * bk
-    hit = ids[:, None] == (seg0 + jax.lax.broadcasted_iota(
-        jnp.int32, (1, bk), 1))                           # [bn, bk]
+    ids = ids_ref[...]                                    # [1, bn]
+    vals = val_ref[...]                                   # [bd, bn]
+    bn = ids.shape[1]
+    hit = (k * bk + jax.lax.broadcasted_iota(jnp.int32, (bk, bn), 0)) == ids
     if op == "+":
+        # [bd, bn] · [bk, bn]ᵀ → [bd, bk], contracting the lane (row) axis
         out_ref[...] += jax.lax.dot_general(
-            hit.astype(acc), vals, (((0,), (0,)), ((), ())),
-            preferred_element_type=acc)                   # [bk, bd]
+            vals, hit.astype(acc), (((1,), (1,)), ((), ())),
+            precision=F32_DOT if acc == jnp.float32 else None,
+            preferred_element_type=acc)
     else:
-        # one-hot select: rows not in this segment carry the ⊕ identity,
-        # then reduce over the row axis and merge into the accumulator
-        sel = jnp.where(hit[:, :, None], vals[:, None, :],
-                        ident)                            # [bn, bk, bd]
+        # one-hot select: rows not in a segment carry the ⊕ identity,
+        # reduce over lanes to a [bk, 1] column, then move the column onto
+        # lanes with a diagonal select-and-reduce (exact: values are only
+        # selected, never combined arithmetically)
         red = jnp.min if op == "min" else jnp.max
         comb = jnp.minimum if op == "min" else jnp.maximum
-        out_ref[...] = comb(out_ref[...], red(sel, axis=0))
+        eye = (jax.lax.broadcasted_iota(jnp.int32, (bk, bk), 0)
+               == jax.lax.broadcasted_iota(jnp.int32, (bk, bk), 1))
+        for d in range(bd):
+            col = red(jnp.where(hit, vals[d:d + 1, :], ident), axis=1,
+                      keepdims=True)                      # [bk, 1]
+            row = red(jnp.where(eye, col, ident), axis=0,
+                      keepdims=True)                      # [1, bk]
+            out_ref[d:d + 1, :] = comb(out_ref[d:d + 1, :], row)
 
 
 @functools.partial(jax.jit, static_argnames=("num_segments", "op", "bn",
                                              "bk", "bd", "interpret"))
 def segment_reduce(ids: jax.Array, values: jax.Array, num_segments: int,
-                   *, op: str = "+", bn: int = 256, bk: int = 128,
-                   bd: int = 128, interpret: bool = True) -> jax.Array:
+                   *, op: str = "+", bn: int = 1024, bk: int = 256,
+                   bd: int = 8, interpret: bool = True) -> jax.Array:
     """ids: [N] int; values: [N] or [N, D] -> [num_segments(, D)].
     op ∈ {"+", "min", "max"}.  Integer values take the exact-int path
-    (int32 accumulation); floats accumulate in float32."""
+    (int32 accumulation); floats accumulate in float32.  The defaults are
+    TPU-aligned blocks (bn, bk multiples of 128; bd ≤ 8 or D itself)."""
     if op not in ("+", "min", "max"):
         raise ValueError(f"segment_reduce: unsupported op {op!r}")
     squeeze = values.ndim == 1
@@ -85,44 +106,47 @@ def segment_reduce(ids: jax.Array, values: jax.Array, num_segments: int,
     n, d = values.shape
     acc = jnp.int32 if jnp.issubdtype(values.dtype, jnp.integer) \
         else jnp.float32
-    bn = min(bn, n)
-    bk = min(bk, num_segments)
+    bn = min(bn, _round_up(n, _LANES))
+    bk = min(bk, _round_up(num_segments, _LANES))
     bd = min(bd, d)
     # pad to block multiples; padded rows get id = Kp (matches no k block)
-    np_ = -(-n // bn) * bn
-    kp = -(-num_segments // bk) * bk
-    dp = -(-d // bd) * bd
+    np_ = _round_up(n, bn)
+    kp = _round_up(num_segments, bk)
+    dp = _round_up(d, bd)
     ident = jnp.asarray(_int_identity(op) if acc == jnp.int32
                         else _IDENTITY[op], acc)
     ids32 = ids.astype(jnp.int32)
     values = values.astype(acc)
     if op == "+":
         # dropped rows (id < 0 or ≥ num_segments) hit an all-zero one-hot
-        # row, but 0 × inf/NaN would still contaminate the MXU dot — zero
-        # their values so they contribute nothing regardless of content
-        # (min/max use a pure select, which never multiplies)
+        # column, but 0 × inf/NaN would still contaminate the MXU dot —
+        # zero their values so they contribute nothing regardless of
+        # content (min/max use a pure select, which never multiplies)
         keep = (ids32 >= 0) & (ids32 < num_segments)
         values = jnp.where(keep[:, None], values, jnp.zeros((), acc))
-    ids_p = jnp.full((np_,), kp, jnp.int32).at[:n].set(ids32)
-    vals_p = jnp.full((np_, dp), ident, acc).at[:n, :d].set(values)
+    ids_p = jnp.full((1, np_), kp, jnp.int32).at[0, :n].set(ids32)
+    vals_p = jnp.full((dp, np_), ident, acc).at[:d, :n].set(values.T)
+    # inside shard_map the output varies over the mesh axes its operands
+    # vary over: the annotation shard_map's replication check reads
+    vma = jax.typeof(ids_p).vma | jax.typeof(vals_p).vma
 
     out = pl.pallas_call(
-        functools.partial(_kernel, bk=bk, op=op, acc=acc),
+        functools.partial(_kernel, bk=bk, bd=bd, op=op, acc=acc),
         grid=(kp // bk, dp // bd, np_ // bn),
         in_specs=[
-            pl.BlockSpec((bn,), lambda k, dd, nn: (nn,)),
-            pl.BlockSpec((bn, bd), lambda k, dd, nn: (nn, dd)),
+            pl.BlockSpec((1, bn), lambda k, dd, nn: (0, nn)),
+            pl.BlockSpec((bd, bn), lambda k, dd, nn: (dd, nn)),
         ],
-        out_specs=pl.BlockSpec((bk, bd), lambda k, dd, nn: (k, dd)),
-        out_shape=jax.ShapeDtypeStruct((kp, dp), acc),
+        out_specs=pl.BlockSpec((bd, bk), lambda k, dd, nn: (dd, k)),
+        out_shape=jax.ShapeDtypeStruct((dp, kp), acc, vma=vma),
         interpret=interpret,
     )(ids_p, vals_p)
-    out = out[:num_segments, :d]
+    out = out[:d, :num_segments].T
     return out[:, 0] if squeeze else out
 
 
 def segment_sum(ids: jax.Array, values: jax.Array, num_segments: int,
-                *, bn: int = 256, bk: int = 128, bd: int = 128,
+                *, bn: int = 1024, bk: int = 256, bd: int = 8,
                 interpret: bool = True) -> jax.Array:
     """ids: [N] int32; values: [N, D] -> [num_segments, D] float32.
     Kept as the historical fp32 entry point; `segment_reduce` is the

@@ -10,6 +10,14 @@ from __future__ import annotations
 
 import jax
 import numpy as np
+from jax.sharding import AxisType
+
+
+def _auto(axes) -> tuple:
+    """Auto axes: the plan's collectives and gspmd's partitioner decide the
+    layouts (jax.make_mesh otherwise makes Explicit axes, which reject
+    sharded contracting dimensions)."""
+    return (AxisType.Auto,) * len(axes)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -21,13 +29,16 @@ def make_production_mesh(*, multi_pod: bool = False):
         raise RuntimeError(
             f"mesh {shape} needs {need} devices, found {len(devs)} — run under "
             f"XLA_FLAGS=--xla_force_host_platform_device_count=512 (see dryrun.py)")
-    return jax.make_mesh(shape, axes, devices=devs[:need])
+    return jax.make_mesh(shape, axes, devices=devs[:need],
+                         axis_types=_auto(axes))
 
 
 def make_test_mesh(shape=(2, 2), axes=("data", "model")):
-    """Small mesh for unit tests (requires forced host devices)."""
+    """Mesh over the first prod(shape) devices: forced host devices in the
+    tests, the chips of a TPU host in chip_smoke.py."""
     need = int(np.prod(shape))
-    return jax.make_mesh(shape, axes, devices=jax.devices()[:need])
+    return jax.make_mesh(shape, axes, devices=jax.devices()[:need],
+                         axis_types=_auto(axes))
 
 
 def axis_sizes(mesh) -> dict[str, int]:

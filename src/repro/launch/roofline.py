@@ -26,8 +26,7 @@ def param_counts(model) -> tuple[int, int]:
     cfg = model.cfg
     total = 0
     expert = 0
-    from repro.compat import tree_flatten_with_path
-    for path, leaf in tree_flatten_with_path(model.abstract_params())[0]:
+    for path, leaf in jax.tree.flatten_with_path(model.abstract_params())[0]:
         keys = [getattr(p, "key", str(p)) for p in path]
         if keys[-1] == "embed" and len(keys) == 1:
             continue

@@ -110,8 +110,7 @@ def tree_pspecs(defs, axis_sizes):
 
 
 def tree_init(defs, seed: int):
-    from ..compat import tree_flatten_with_path
-    leaves, treedef = tree_flatten_with_path(defs, is_leaf=is_def)
+    leaves, treedef = jax.tree.flatten_with_path(defs, is_leaf=is_def)
     out = []
     base = jax.random.PRNGKey(seed)
     for path, d in leaves:

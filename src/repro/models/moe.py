@@ -27,7 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..compat import shard_map
 from .common import ParamDef, dense
 
 
@@ -191,7 +190,7 @@ def _moe_ep_alltoall(cfg, p, x, mesh, dp_axes):
 
     dp = tuple(dp_axes)
     wdm = "data" if cfg.fsdp_experts else None
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(None, None), P("model", wdm, None), P("model", wdm, None),
                   P("model", None, wdm), P(dp, "model", None)),
@@ -233,7 +232,7 @@ def _moe_ep_localexperts(cfg, p, x, mesh, dp_axes):
 
     dp = tuple(dp_axes)
     wdm = "data" if cfg.fsdp_experts else None
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(None, None), P("model", wdm, None), P("model", wdm, None),
                   P("model", None, wdm), P(dp, None, None)),

@@ -74,7 +74,6 @@ class PeerReplica:
             return np.array(x)          # host mirror (defensive copy)
         import jax
         from jax.sharding import PartitionSpec as P
-        from ..compat import shard_map
         key = (tuple(x.shape), str(x.dtype), inverse)
         fn = self._shift.get(key)
         if fn is None:
@@ -84,9 +83,9 @@ class PeerReplica:
 
             def body(b):
                 return jax.lax.ppermute(b, self.dp, perm)
-            fn = jax.jit(shard_map(body, mesh=self.mesh,
-                                   in_specs=(P(self.dp),),
-                                   out_specs=P(self.dp)))
+            fn = jax.jit(jax.shard_map(body, mesh=self.mesh,
+                                       in_specs=(P(self.dp),),
+                                       out_specs=P(self.dp)))
             self._shift[key] = fn
         return fn(x)
 

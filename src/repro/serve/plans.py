@@ -126,12 +126,13 @@ class _Bucket:
     """One shape class of one program: the queue plus its counters."""
 
     __slots__ = ("key", "cp", "program", "label", "static", "bag_pads",
-                 "arr_pads", "limit_bags", "limit_arrays", "tickets",
+                 "arr_pads", "salts", "limit_bags", "limit_arrays", "tickets",
                  "flushes", "reqs", "traced", "hits", "real_lanes", "lanes",
                  "pad_rows", "bag_rows", "failed_flushes", "est_peak",
                  "lane_cap")
 
-    def __init__(self, key, cp, program, label, static, bag_pads, arr_pads):
+    def __init__(self, key, cp, program, label, static, bag_pads, arr_pads,
+                 salts):
         self.key = key
         self.cp = cp
         self.program = program
@@ -139,6 +140,7 @@ class _Bucket:
         self.static = static               # dim name → value
         self.bag_pads = bag_pads           # bag name → padded row count
         self.arr_pads = arr_pads           # array name → padded dim-0
+        self.salts = salts                 # dest → hot-key salt factor
         self.limit_bags = tuple(sorted(bag_pads))
         self.limit_arrays = tuple(sorted(arr_pads))
         self.tickets: deque = deque()
@@ -333,12 +335,19 @@ class PlanServer:
                 if name in arr_pads:
                     shp = (arr_pads[name],) + shp[1:]
                 psig.append((name, t.kind, shp, str(np.asarray(v).dtype)))
-        key = (program, tuple(psig), frozenset(arr_pads))
+        # the hot-key salt factors a solo run() of THIS request would
+        # probe: they change the traced reduction, so they key the bucket
+        from ..core.lower import collect_salts
+        salts = collect_salts(cp.plan, cin, cp.selector,
+                              cp.config.skew_salting)
+        key = (program, tuple(psig), frozenset(arr_pads),
+               tuple(sorted(salts.items())))
         b = self._buckets.get(key)
         if b is None:
             b = _Bucket(key, cp, program, self._label(program, key, static,
-                                                      bag_pads, arr_pads),
-                        static, bag_pads, arr_pads)
+                                                      bag_pads, arr_pads,
+                                                      salts),
+                        static, bag_pads, arr_pads, salts)
             self._mem_size(b, tuple(psig))
             self._buckets[key] = b
         return b
@@ -370,10 +379,11 @@ class PlanServer:
         return n
 
     @staticmethod
-    def _label(program, key, static, bag_pads, arr_pads) -> str:
+    def _label(program, key, static, bag_pads, arr_pads, salts) -> str:
         parts = [f"{n}:{L}" for n, L in bag_pads.items()]
         parts += [f"{n}:{L}" for n, L in sorted(arr_pads.items())]
         parts += [f"{n}={v}" for n, v in static.items()]
+        parts += [f"salt[{n}]={s}" for n, s in sorted(salts.items())]
         h = hashlib.md5(repr(key).encode()).hexdigest()[:4]
         return f"{program}{{{' '.join(parts)}}}#{h}"
 
@@ -528,7 +538,7 @@ class PlanServer:
 
         def call(buf=arrays):
             return b.cp.batched_call((b.key, Bp), b.static, buf, lengths,
-                                     b.limit_bags, b.limit_arrays)
+                                     b.limit_bags, b.limit_arrays, b.salts)
 
         def attempt():
             F.site("serve.batched_call", program=b.program, rids=rids)

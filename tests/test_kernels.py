@@ -133,6 +133,24 @@ def test_segment_reduce_exact_int_accumulation():
     assert m.dtype == jnp.int32 and int(m[0]) == 2**24 + 1
 
 
+@pytest.mark.parametrize("d", [1, 3])
+def test_segment_reduce_int_sum_wraps_like_scatter(d):
+    # the int + path accumulates in int32: full-range values (negatives,
+    # sums that overflow) must equal the wrapping int32 scatter-add bit for
+    # bit, across several id blocks
+    r = np.random.default_rng(5)
+    n, k = 3000, 37
+    ids = r.integers(-3, k + 3, n).astype(np.int32)
+    vals = r.integers(-2**31, 2**31 - 1, (n, d), dtype=np.int64) \
+        .astype(np.int32)
+    vals = vals[:, 0] if d == 1 else vals
+    a = segment_reduce(jnp.asarray(ids), jnp.asarray(vals), k, bn=1024,
+                       bk=128)
+    b = segment_reduce_ref(jnp.asarray(ids), jnp.asarray(vals), k)
+    assert a.dtype == jnp.int32
+    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
 @pytest.mark.parametrize("m,k,n,bm", [(64, 32, 48, 32), (100, 70, 90, 32),
                                       (33, 17, 9, 16), (128, 128, 128, 128)])
 def test_tile_matmul_shapes(m, k, n, bm):

@@ -57,6 +57,42 @@ def test_cost_model_decision_table_tpu():
         assert dec.backend != "pallas", (n, k, dec)
 
 
+@pytest.mark.parametrize("dtype", ["int32", "uint32"])
+def test_tpu_never_picks_pallas_for_an_integer_sum(dtype):
+    # v5e's MXU has no int32 dot, so Mosaic refuses the kernel's exact-int
+    # sum: the TPU row must route integer sums elsewhere, even when forced
+    for mode in ("cost", "force:pallas"):
+        sel = OpSelector(mode=mode, cache_path=None, platform="tpu")
+        for n, k in [(1 << 24, 256), (1 << 27, 64)]:
+            assert sel.choose_segment(n=n, k=k, d=1, op="+",
+                                      dtype=dtype).backend != "pallas"
+    # float sums and integer min/max keep the kernel
+    sel = OpSelector(mode="cost", cache_path=None, platform="tpu")
+    assert sel.choose_segment(n=1 << 24, k=256, d=1, op="+",
+                              dtype="float32").backend == "pallas"
+    assert sel.choose_segment(n=1 << 24, k=256, d=1, op="min",
+                              dtype=dtype).backend == "pallas"
+
+
+@pytest.mark.parametrize("platform,mode,expect", [
+    ("cpu", "cost", False), ("cpu", "force:scatter", False),
+    ("cpu", "force:pallas", True), ("cpu", "force:pallas-tiled", True),
+    ("tpu", "cost", True)])
+def test_may_pick_pallas_only_where_a_kernel_can_be_chosen(platform, mode,
+                                                            expect):
+    # distributed rounds keep shard_map's replication check unless this
+    # says an interpreted kernel may run in them
+    sel = OpSelector(mode=mode, cache_path=None, platform=platform)
+    assert sel.may_pick_pallas() is expect
+
+
+def test_cost_model_refuses_a_platform_without_a_cost_row():
+    # an unknown backend must not silently borrow the CPU's constants
+    sel = OpSelector(mode="cost", cache_path=None, platform="metal")
+    with pytest.raises(ValueError, match="no cost table.*'metal'"):
+        sel.choose_segment(n=4096, k=64, d=1, op="+", dtype="float32")
+
+
 def test_candidate_sets_respect_monoid():
     assert "onehot" not in SEGMENT_CANDIDATES["min"]   # onehot only sums
     assert "onehot" not in SEGMENT_CANDIDATES["*"]

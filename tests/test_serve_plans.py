@@ -262,3 +262,29 @@ def test_bag_row_aligned_analysis():
         "D": "P", "MinD": "P", "Cl": "P"}
     assert cps()["pagerank"].bag_row_aligned == {}
     assert cps()["group_by"].bag_row_aligned == {}
+
+
+def test_probe_salted_request_serves_like_solo_run(fake_clock):
+    """Where the cost model salts a hot key (TPU costs), a solo run()
+    salts from its run-time probe; the served request must take the same
+    salted trace — the probe runs host-side at submit and keys the bucket
+    — or the two reduce in different orders and differ in the last bit."""
+    cp = compile_program(progs.group_by, op_select="force:scatter")
+    cp.selector._platform = "tpu"          # its cost row salts hot keys
+    rng = np.random.default_rng(3)
+
+    def req():
+        keys = np.where(rng.random(1024) < 0.5, 7, rng.integers(0, 64, 1024))
+        return dict(S=(keys.astype(np.float64), rng.standard_normal(1024)),
+                    C=np.zeros(64))
+
+    reqs = [req() for _ in range(2)]
+    srv = PlanServer({"group_by": cp}, clock=fake_clock, max_batch=2)
+    tickets = [srv.submit("group_by", deep_copy(r)) for r in reqs]
+    assert srv.pump() == 2
+    (label,) = srv.stats()["buckets"]
+    assert "salt[C]=16" in label, label
+    for r, t in zip(reqs, tickets):
+        solo = cp.run(deep_copy(r))
+        assert "salt=16x[probe]" in cp.explain()
+        np.testing.assert_array_equal(t.output["C"], np.asarray(solo["C"]))

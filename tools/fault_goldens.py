@@ -172,8 +172,10 @@ def main() -> None:
 
 if __name__ == "__main__":
     import os
-    # before jax loads: the shard-loss scenario needs a 4-way mesh
+    # before jax loads: the shard-loss scenario needs a 4-way mesh of
+    # forced host devices, so the goldens always come from the CPU backend
     os.environ.setdefault("XLA_FLAGS",
                           "--xla_force_host_platform_device_count=4")
+    os.environ["JAX_PLATFORMS"] = "cpu"
     sys.path.insert(0, "src")
     main()
