@@ -93,6 +93,12 @@ REDUCE = {"+": jnp.sum, "*": jnp.prod, "min": jnp.min, "max": jnp.max}
 COMBINE = {"+": jnp.add, "*": jnp.multiply, "min": jnp.minimum,
            "max": jnp.maximum}
 
+# host spans of run() (repro.run and its children, README "Tracing a
+# job"): written into a running profiler's trace on the device trace's
+# clock.  Constant names and no metadata, so with no profiler running a
+# span costs about a microsecond
+_span = jax.profiler.TraceAnnotation
+
 
 def identity(op: str, dtype) -> jnp.ndarray:
     """The ⊕ identity element for masked-out rows."""
@@ -451,7 +457,8 @@ class PlanExecutor:
                     # sequencing of the body, same results
                     self.execute(node.body, env, ctx)
                     continue
-                self._exec_seq_loop(node, env, ctx)
+                with jax.named_scope("SeqLoop"):
+                    self._exec_seq_loop(node, env, ctx)
             elif isinstance(node, P.FusedRound):
                 # round-fusion region: plain sequencing on a single device
                 # (the grouping only matters to the distributed executor)
@@ -463,6 +470,15 @@ class PlanExecutor:
                 env[node.dest] = self.run_node(node, env, ctx)
 
     def run_node(self, node, env, ctx: ExecContext = _EMPTY_CTX):
+        # every op the node lowers to carries the named scope
+        # `<NodeType>:<dest>` (a Fused node's dests joined by "+") in its
+        # HLO metadata, which the device trace reports per op.  Trace
+        # time only: the compiled program runs no differently
+        with jax.named_scope(
+                f"{type(node).__name__}:{'+'.join(P.dests_of(node))}"):
+            return self._run_node(node, env, ctx)
+
+    def _run_node(self, node, env, ctx: ExecContext):
         # per-node guard site (DESIGN.md §11): under jit this fires at
         # trace time — a fault here fails the whole-program trace, whose
         # ladder then descends to the eager path where it fires again
@@ -667,17 +683,23 @@ class PlanExecutor:
         return dec.backend
 
     def _exec_segment(self, node: P.SegmentReduce, env, ctx):
-        ax, binding, conds, base = self.build_space(node.space, env, ctx)
+        # named scopes split the group-by's ops by stage: keys (the key
+        # grid, row masks, ravel and salting), value, then in
+        # _segment_flat sort / permute / reduce, then salt_fold, combine
         dest = env[node.dest]
-        masks = list(base)
-        keys = [self.eval(k, env, ax, binding, masks, ctx)
-                for k in node.keys]
-        val = self.eval(node.value, env, ax, binding, masks, ctx)
-        m = self._mask(conds, env, ax, binding, masks, ctx)
+        with jax.named_scope("keys"):
+            ax, binding, conds, base = self.build_space(node.space, env, ctx)
+            masks = list(base)
+            keys = [self.eval(k, env, ax, binding, masks, ctx)
+                    for k in node.keys]
         shape = ax.shape()
-        val = jnp.broadcast_to(val, shape)
-        kk = [jnp.broadcast_to(jnp.asarray(k, jnp.int32), shape)
-              for k in keys]
+        with jax.named_scope("value"):
+            val = jnp.broadcast_to(
+                self.eval(node.value, env, ax, binding, masks, ctx), shape)
+        with jax.named_scope("keys"):
+            m = self._mask(conds, env, ax, binding, masks, ctx)
+            kk = [jnp.broadcast_to(jnp.asarray(k, jnp.int32), shape)
+                  for k in keys]
         lim0 = ctx.array_limits.get(node.dest)
         n_rows = 1
         for d_ in shape:
@@ -695,25 +717,30 @@ class PlanExecutor:
             # scatter — salting exists to break its duplicate-update
             # serialization, and scattering into the [K·S] identity-filled
             # partial is exactly how the chain length divides by S.
-            flat, num = self._ravel_keys([k.reshape(-1) for k in kk],
-                                         dest.shape, limit0=lim0)
-            if m is not None:
-                flat = jnp.where(m.reshape(-1), flat, num)
-            off = 0
-            lead = node.space.axes[0] if node.space.axes else None
-            if lead is not None and lead.kind == "bag":
-                off = ctx.bag_offsets.get(lead.bag, 0)
-            salt = (off + jnp.arange(flat.shape[0], dtype=jnp.int32)) % salt_s
-            salted = jnp.where(flat < num, flat * salt_s + salt,
-                               num * salt_s)
-            vflat = val.reshape(-1).astype(dest.dtype)
+            with jax.named_scope("keys"):
+                flat, num = self._ravel_keys([k.reshape(-1) for k in kk],
+                                             dest.shape, limit0=lim0)
+                if m is not None:
+                    flat = jnp.where(m.reshape(-1), flat, num)
+                off = 0
+                lead = node.space.axes[0] if node.space.axes else None
+                if lead is not None and lead.kind == "bag":
+                    off = ctx.bag_offsets.get(lead.bag, 0)
+                salt = (off + jnp.arange(flat.shape[0], dtype=jnp.int32)) \
+                    % salt_s
+                salted = jnp.where(flat < num, flat * salt_s + salt,
+                                   num * salt_s)
+            with jax.named_scope("value"):
+                vflat = val.reshape(-1).astype(dest.dtype)
             part = self._segment_flat(backend, salted, vflat,
                                       num * salt_s, node.op)
-            part = REDUCE[node.op](part.reshape(num, salt_s), axis=1)
+            with jax.named_scope("salt_fold"):
+                part = REDUCE[node.op](part.reshape(num, salt_s), axis=1)
             self.note(node, self.decisions.get(id(node), "")
                       + f" salt={salt_s}x[{salt_src}]")
-            return COMBINE[node.op](
-                dest, part.reshape(dest.shape).astype(dest.dtype))
+            with jax.named_scope("combine"):
+                return COMBINE[node.op](
+                    dest, part.reshape(dest.shape).astype(dest.dtype))
         if backend != "scatter":
             # flattened-segment backends (sort / onehot / pallas): ravel
             # the key tuple against the physical dims, route every dropped
@@ -721,14 +748,17 @@ class PlanExecutor:
             # the sentinel segment `num`, reduce into a [num] partial and
             # ⊕-combine with the destination.  Empty segments carry the ⊕
             # identity in the partial, so the combine leaves them alone.
-            flat, num = self._ravel_keys([k.reshape(-1) for k in kk],
-                                         dest.shape, limit0=lim0)
-            if m is not None:
-                flat = jnp.where(m.reshape(-1), flat, num)  # dropped
-            vflat = val.reshape(-1).astype(dest.dtype)
+            with jax.named_scope("keys"):
+                flat, num = self._ravel_keys([k.reshape(-1) for k in kk],
+                                             dest.shape, limit0=lim0)
+                if m is not None:
+                    flat = jnp.where(m.reshape(-1), flat, num)  # dropped
+            with jax.named_scope("value"):
+                vflat = val.reshape(-1).astype(dest.dtype)
             seg = self._segment_flat(backend, flat, vflat, num, node.op)
-            return COMBINE[node.op](
-                dest, seg.reshape(dest.shape).astype(dest.dtype))
+            with jax.named_scope("combine"):
+                return COMBINE[node.op](
+                    dest, seg.reshape(dest.shape).astype(dest.dtype))
         # native scatter-⊕ straight into the destination with drop
         # semantics — no identity-filled segment array, no index
         # flattening.  Keys are reinterpreted as uint32: a negative key
@@ -741,13 +771,17 @@ class PlanExecutor:
         # value gather, a padded row — scatter the ⊕ IDENTITY instead:
         # contributing the identity is contributing nothing (and it also
         # scrubs the non-finite values a dropped row may carry).
-        val = val.astype(dest.dtype)
-        if m is not None:
-            val = jnp.where(m, val, identity(node.op, dest.dtype))
-        if lim0 is not None:      # logical dim-0 bound (padded rows)
-            kk[0] = jnp.where(kk[0] >= lim0, dest.shape[0], kk[0])
-        kk = [k.astype(jnp.uint32) for k in kk]
-        return _scatter_op(dest.at[tuple(kk)], node.op)(val, mode="drop")
+        with jax.named_scope("value"):
+            val = val.astype(dest.dtype)
+            if m is not None:
+                val = jnp.where(m, val, identity(node.op, dest.dtype))
+        with jax.named_scope("keys"):
+            if lim0 is not None:      # logical dim-0 bound (padded rows)
+                kk[0] = jnp.where(kk[0] >= lim0, dest.shape[0], kk[0])
+            kk = [k.astype(jnp.uint32) for k in kk]
+        with jax.named_scope("reduce"):
+            return _scatter_op(dest.at[tuple(kk)], node.op)(val,
+                                                            mode="drop")
 
     def _segment_salt(self, node: P.SegmentReduce, ctx, dest):
         """Resolve the hot-key salt factor for this node: the static hint
@@ -775,18 +809,24 @@ class PlanExecutor:
             # Sentinel rows land in the discard row and are sliced off —
             # dropped rows may carry non-finite values, but they only ever
             # touch buf[num].
-            buf = jnp.full((num + 1,), identity(op, vals.dtype), vals.dtype)
-            return _scatter_op(buf.at[ids], op)(vals)[:num]
+            with jax.named_scope("reduce"):
+                buf = jnp.full((num + 1,), identity(op, vals.dtype),
+                               vals.dtype)
+                return _scatter_op(buf.at[ids], op)(vals)[:num]
         if backend == "sort":
             # sort-based: jax.ops.segment_⊕ over sorted ids (the classic
             # GPU/TPU shape).  num+1 segments so the sentinel rows land in
             # a discard row — deterministic drop without scatter modes.
-            order = jnp.argsort(ids)
+            with jax.named_scope("sort"):
+                order = jnp.argsort(ids)
+            with jax.named_scope("permute"):
+                vals, ids = vals[order], ids[order]
             seg = {"+": jax.ops.segment_sum, "min": jax.ops.segment_min,
                    "max": jax.ops.segment_max,
                    "*": jax.ops.segment_prod}[op]
-            return seg(vals[order], ids[order], num_segments=num + 1,
-                       indices_are_sorted=True)[:num]
+            with jax.named_scope("reduce"):
+                return seg(vals, ids, num_segments=num + 1,
+                           indices_are_sorted=True)[:num]
         if backend == "onehot":
             # group-by as matmul: [N, num] one-hot × [N] values on the
             # MXU.  Integer values take the exact-int path (int32
@@ -797,15 +837,17 @@ class PlanExecutor:
             # a division), and drop semantics say they contribute nothing
             acc = vals.dtype if jnp.issubdtype(vals.dtype, jnp.integer) \
                 else jnp.float32
-            vals = jnp.where(ids == num, jnp.zeros((), vals.dtype), vals)
-            oh = (ids[:, None] == jnp.arange(num)[None, :]).astype(acc)
-            return jax.lax.dot_general(
-                vals.astype(acc)[None, :], oh, (((1,), (0,)), ((), ())),
-                precision=xla_f32_dot(),
-                preferred_element_type=acc)[0]
+            with jax.named_scope("reduce"):
+                vals = jnp.where(ids == num, jnp.zeros((), vals.dtype), vals)
+                oh = (ids[:, None] == jnp.arange(num)[None, :]).astype(acc)
+                return jax.lax.dot_general(
+                    vals.astype(acc)[None, :], oh, (((1,), (0,)), ((), ())),
+                    precision=xla_f32_dot(),
+                    preferred_element_type=acc)[0]
         if backend == "pallas":
             from ..kernels import ops as kops
-            return kops.segment_reduce(ids, vals, num, op=op)
+            with jax.named_scope("reduce"):
+                return kops.segment_reduce(ids, vals, num, op=op)
         raise RejectionError(f"unknown segment backend {backend!r}")
 
     def _ravel_keys(self, kk, dshape, limit0=None):
@@ -1438,8 +1480,9 @@ class CompiledProgram:
         return tuple(sig)
 
     def _run_whole(self, inputs: dict):
-        env = self.prepare_env(inputs)
-        sig = self._signature(env)
+        with _span("repro.prepare"):
+            env = self.prepare_env(inputs)
+            sig = self._signature(env)
         if sig is None:
             return None                       # packed inputs: eager path
         static = {n: v for n, v in env.items() if isinstance(v, int)}
@@ -1457,9 +1500,11 @@ class CompiledProgram:
                 if n not in static and n not in donated}
         # run-time hot-key probe (skew salting): the resolved factors are
         # part of the cache key — a skewed and a uniform key stream of the
-        # same shapes trace DIFFERENT programs
-        salts = collect_salts(self.plan, env, self.selector,
-                              self.config.skew_salting)
+        # same shapes trace DIFFERENT programs.  The probe copies up to
+        # 4096 key rows to the host, a device round trip per call
+        with _span("repro.salt_probe"):
+            salts = collect_salts(self.plan, env, self.selector,
+                                  self.config.skew_salting)
         key = (sig, donate, tuple(sorted(salts.items())))
         left = self._whole_bad.get(key)
         if left is not None:
@@ -1490,8 +1535,10 @@ class CompiledProgram:
                 F.site("lower.whole_trace", program=self.program.name)
                 return fn(donated, kept)      # traces the whole plan once
             try:
-                out = F.run_with_retries(attempt, policy=self.policy,
-                                         ledger=self.faults, label="whole")
+                with _span("repro.compile"):    # trace, compile, 1st call
+                    out = F.run_with_retries(attempt, policy=self.policy,
+                                             ledger=self.faults,
+                                             label="whole")
             except Exception as ex:           # noqa: BLE001 — ladder
                 self.trace_failures += 1
                 self._whole_bad[key] = self.policy.disable_ttl
@@ -1511,9 +1558,14 @@ class CompiledProgram:
         # cached signatures skip the trace: restore the decision snapshot
         # taken when this signature was traced, so explain() stays accurate
         self.executor.decisions.update(notes)
-        return fn(donated, kept)
+        with _span("repro.enqueue"):
+            return fn(donated, kept)
 
     def run(self, inputs: dict) -> dict:
+        with _span("repro.run"):
+            return self._run(inputs)
+
+    def _run(self, inputs: dict) -> dict:
         # hard admission check (DESIGN.md §12): calls whose estimated
         # peak exceeds the memory budget stream chunked from the start
         if self._ooc_admits(inputs):

@@ -76,4 +76,5 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         out_specs=pl.BlockSpec((1, bq, hd), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
+        name="flash_attention",
     )(q, k, v)

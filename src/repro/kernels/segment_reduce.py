@@ -115,17 +115,21 @@ def segment_reduce(ids: jax.Array, values: jax.Array, num_segments: int,
     dp = _round_up(d, bd)
     ident = jnp.asarray(_int_identity(op) if acc == jnp.int32
                         else _IDENTITY[op], acc)
-    ids32 = ids.astype(jnp.int32)
-    values = values.astype(acc)
-    if op == "+":
-        # dropped rows (id < 0 or ≥ num_segments) hit an all-zero one-hot
-        # column, but 0 × inf/NaN would still contaminate the MXU dot —
-        # zero their values so they contribute nothing regardless of
-        # content (min/max use a pure select, which never multiplies)
-        keep = (ids32 >= 0) & (ids32 < num_segments)
-        values = jnp.where(keep[:, None], values, jnp.zeros((), acc))
-    ids_p = jnp.full((1, np_), kp, jnp.int32).at[0, :n].set(ids32)
-    vals_p = jnp.full((dp, np_), ident, acc).at[:d, :n].set(values.T)
+    # the pads and transposes into the lane-dense layout, and back, carry
+    # the named scope "layout": the device trace tells them from the kernel
+    with jax.named_scope("layout"):
+        ids32 = ids.astype(jnp.int32)
+        values = values.astype(acc)
+        if op == "+":
+            # dropped rows (id < 0 or ≥ num_segments) hit an all-zero
+            # one-hot column, but 0 × inf/NaN would still contaminate the
+            # MXU dot — zero their values so they contribute nothing
+            # regardless of content (min/max use a pure select, which
+            # never multiplies)
+            keep = (ids32 >= 0) & (ids32 < num_segments)
+            values = jnp.where(keep[:, None], values, jnp.zeros((), acc))
+        ids_p = jnp.full((1, np_), kp, jnp.int32).at[0, :n].set(ids32)
+        vals_p = jnp.full((dp, np_), ident, acc).at[:d, :n].set(values.T)
     # inside shard_map the output varies over the mesh axes its operands
     # vary over: the annotation shard_map's replication check reads
     vma = jax.typeof(ids_p).vma | jax.typeof(vals_p).vma
@@ -140,9 +144,11 @@ def segment_reduce(ids: jax.Array, values: jax.Array, num_segments: int,
         out_specs=pl.BlockSpec((bd, bk), lambda k, dd, nn: (dd, k)),
         out_shape=jax.ShapeDtypeStruct((dp, kp), acc, vma=vma),
         interpret=interpret,
+        name="segment_reduce",
     )(ids_p, vals_p)
-    out = out[:d, :num_segments].T
-    return out[:, 0] if squeeze else out
+    with jax.named_scope("layout"):
+        out = out[:d, :num_segments].T
+        return out[:, 0] if squeeze else out
 
 
 def segment_sum(ids: jax.Array, values: jax.Array, num_segments: int,

@@ -68,4 +68,5 @@ def selective_scan(a: jax.Array, bx: jax.Array, c: jax.Array, *,
         out_specs=pl.BlockSpec((1, s, bd), lambda i, j: (i, 0, j)),
         out_shape=jax.ShapeDtypeStruct((b, s, d), jnp.float32),
         interpret=interpret,
+        name="selective_scan",
     )(a.astype(jnp.float32), bx.astype(jnp.float32), c.astype(jnp.float32))

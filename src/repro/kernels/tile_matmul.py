@@ -73,6 +73,7 @@ def tile_matmul(a: jax.Array, b: jax.Array, *, bm: int = 128, bn: int = 128,
             out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
             out_shape=out_shape,
             interpret=interpret,
+            name="tile_matmul",
         )(ap, bp)
     else:
         # the flat int32 mask rides in SMEM (scalar prefetch), so a grid
@@ -90,5 +91,6 @@ def tile_matmul(a: jax.Array, b: jax.Array, *, bm: int = 128, bn: int = 128,
                                        lambda i, j, kk, m: (i, j))),
             out_shape=out_shape,
             interpret=interpret,
+            name="tile_matmul_masked",
         )(mask, ap, bp)
     return out[:m, :n]
