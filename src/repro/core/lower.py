@@ -685,7 +685,7 @@ class PlanExecutor:
     def _exec_segment(self, node: P.SegmentReduce, env, ctx):
         # named scopes split the group-by's ops by stage: keys (the key
         # grid, row masks, ravel and salting), value, then in
-        # _segment_flat sort / permute / reduce, then salt_fold, combine
+        # _segment_flat sort / reduce, then salt_fold, combine
         dest = env[node.dest]
         with jax.named_scope("keys"):
             ax, binding, conds, base = self.build_space(node.space, env, ctx)
@@ -815,12 +815,14 @@ class PlanExecutor:
                 return _scatter_op(buf.at[ids], op)(vals)[:num]
         if backend == "sort":
             # sort-based: jax.ops.segment_⊕ over sorted ids (the classic
-            # GPU/TPU shape).  num+1 segments so the sentinel rows land in
-            # a discard row — deterministic drop without scatter modes.
+            # GPU/TPU shape).  One key/value sort carries the values with
+            # their ids, so no gather applies a permutation after it;
+            # stable, so each segment's rows reach the reduction in input
+            # order and float sums do not depend on the sort.  num+1
+            # segments so the sentinel rows land in a discard row —
+            # deterministic drop without scatter modes.
             with jax.named_scope("sort"):
-                order = jnp.argsort(ids)
-            with jax.named_scope("permute"):
-                vals, ids = vals[order], ids[order]
+                ids, vals = jax.lax.sort_key_val(ids, vals, is_stable=True)
             seg = {"+": jax.ops.segment_sum, "min": jax.ops.segment_min,
                    "max": jax.ops.segment_max,
                    "*": jax.ops.segment_prod}[op]

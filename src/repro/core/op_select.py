@@ -10,9 +10,9 @@ shard holds.  This module owns that choice.  Candidate backends:
 
   scatter   native scatter-⊕ with drop semantics (dest.at[keys].⊕); the
             all-rounder on CPU, serialized per duplicate key on TPU
-  sort      sort keys, then jax.ops.segment_⊕ with indices_are_sorted —
-            the classic GPU shape; loses on CPU (measured, see
-            BENCH_kernels.json)
+  sort      one key/value sort, then jax.ops.segment_⊕ with
+            indices_are_sorted — the classic GPU shape; loses on CPU
+            (measured, see BENCH_kernels.json)
   onehot    [N, K] one-hot × [N, D] values on the MXU via dot_general —
             group-by as matmul; wins for small K everywhere (measured ~6x
             over scatter at K=16 even on CPU BLAS)
@@ -206,11 +206,15 @@ def _measure_segment(backend: str, n: int, k: int, d: int, op: str,
         seg = {"+": jax.ops.segment_sum, "min": jax.ops.segment_min,
                "max": jax.ops.segment_max, "*": jax.ops.segment_prod}[op]
 
-        def fn(de, i, v, _seg=seg, _k=k):
-            order = jnp.argsort(i)
+        def fn(de, i, v, _seg=seg):
             from .lower import COMBINE
-            return COMBINE[op](de, _seg(v[order], i[order], num_segments=_k,
-                                        indices_are_sorted=True))
+            if v.ndim == 2:     # the executor ravels key × column into one id
+                d_ = v.shape[1]
+                i = (i[:, None] * d_ + jnp.arange(d_)).reshape(-1)
+            i, vs = jax.lax.sort_key_val(i, v.reshape(-1), is_stable=True)
+            return COMBINE[op](de, _seg(vs, i, num_segments=de.size,
+                                        indices_are_sorted=True)
+                               .reshape(de.shape))
         fn = jax.jit(fn)
     elif backend == "onehot":
         def fn(de, i, v, _k=k):
