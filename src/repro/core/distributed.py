@@ -32,7 +32,7 @@ parameters (lower.ExecContext), not lowerer state.
                            shuffle-based group-by.  When the trace-time
                            hot-key probe (or a static hint) salts the
                            group-by (DESIGN.md §6), the shard-local
-                           partial is computed over key*S+salt
+                           partial is computed over salt*K+key
                            sub-destinations and ⊕-folded back to [K]
                            BEFORE the exchange — the wire format never
                            changes.
@@ -86,11 +86,24 @@ parameters (lower.ExecContext), not lowerer state.
   including range-driven contractions (matmul → partitioned einsum).
 
 Bags AND ONED_ROW dense arrays whose dim-0 length is not divisible by the
-shard count are PADDED with zero rows to the next multiple; the original
+shard count are PADDED with zero rows to the next multiple, block by block:
+each device receives its rows and only the tail blocks get zeros, so no
+padded copy of the whole array is built.  A column that already lies in
+equal row blocks on the mesh (a `RowBlocks`, or an evenly divisible array
+with the dp sharding) is taken as it is, moving no bytes.  The original
 length travels as a bag limit / array limit and the executor masks reads
 and drops writes beyond it, so padding can never change a result (the
 paper's §3.4 empty-bag semantics are enforced against the LOGICAL bound).
 Padded outputs are sliced back to their logical length on return.
+
+Bag columns keep their dtype from input to keys: int32 vertex ids stay
+exact at and above 2**24, where float32 ids would not.
+
+Host spans (`TraceAnnotation`, as in `core/lower.py`): `repro.run` around
+`run()` (the ladder included), `repro.place` around `place()`,
+`repro.round` around each round's call until it returns (the enqueue).
+Device scopes: `Gather:<array>` on each all_gather of a row-block array,
+`Exchange:<dest>` on each cross-shard ⊕ (psum / psum_scatter).
 
 `shard_dense=False` (or `PlanConfig.infer_distributions=False`) restores
 REP-everything — the pre-analysis behaviour and the ⊥ of the lattice.
@@ -107,11 +120,46 @@ from . import faults as F
 from . import plan
 from .dist_analysis import (Dist, aligned_reads, leading_key_var,
                             round_axis, shard_slice_certificates)
-from .lower import (COMBINE, CompiledProgram, ExecContext, identity,
+from .lower import (COMBINE, CompiledProgram, ExecContext, _span, identity,
                     salt_for_node)
 
 _STORE_NODES = (plan.MapExpr, plan.Scatter)
 _ALIGNABLE_REDUCES = (plan.AxisReduce, plan.EinsumContract, plan.TiledMatmul)
+
+
+class RowBlocks:
+    """A column already laid out in equal dim-0 row blocks on a mesh, its
+    tail zero-padded to a multiple of the shard count, with its logical
+    row count: how a caller hands `place()` a bag too large to pad inside
+    `run()`.  `place()` takes `data` as it is and masks rows at and beyond
+    `rows`; everything else (`np.asarray`, `jnp.asarray`, `.astype`) sees
+    the logical rows only."""
+
+    def __init__(self, data, rows: int):
+        if not 0 <= rows <= data.shape[0]:
+            raise ValueError(f"RowBlocks: {rows} logical rows, "
+                             f"{data.shape[0]} physical")
+        self.data = data
+        self.rows = int(rows)
+
+    @property
+    def shape(self) -> tuple:
+        return (self.rows,) + tuple(self.data.shape[1:])
+
+    def __array__(self, dtype=None, copy=None):
+        import numpy as np
+        out = np.asarray(self.data)[:self.rows]
+        return out if dtype is None else out.astype(dtype)
+
+    def __jax_array__(self):
+        return self.data[:self.rows]
+
+    def astype(self, dtype):
+        return self.__jax_array__().astype(dtype)
+
+    def block_until_ready(self):
+        self.data.block_until_ready()
+        return self
 
 
 class DistributedProgram:
@@ -204,11 +252,48 @@ class DistributedProgram:
 
     # ------------------------- input placement -------------------------
     def place(self, inputs: dict):
-        """Shard bags and ONED_ROW dense arrays over dp (padding dim 0 with
-        zero rows to a multiple of the shard count), replicate the rest.
-        Returns (placed, bag_limits, array_limits); the limit dicts map
-        each padded name to its logical dim-0 length — consumers MUST mask
-        rows beyond the limit (run() threads them through ExecContext)."""
+        """Shard bags and ONED_ROW dense arrays over dp as row blocks
+        (`_row_blocks`: dim 0 zero-padded to a multiple of the shard
+        count), replicate the rest.  Returns (placed, bag_limits,
+        array_limits); the limit dicts map each padded name to its logical
+        dim-0 length — consumers MUST mask rows beyond the limit (run()
+        threads them through ExecContext)."""
+        with _span("repro.place"):
+            return self._place(inputs)
+
+    def _row_blocks(self, v):
+        """`v` as equal dim-0 row blocks over dp, and its logical length
+        when the blocks carry pad rows (else None).  A `RowBlocks`, or an
+        array whose length divides evenly and that already has this
+        sharding, is taken as it is: no bytes move.  Otherwise each device
+        receives its own rows; only the blocks at the tail get zero rows,
+        so no padded copy of the whole array is ever built."""
+        sh = NamedSharding(self.mesh, P(self.dp))
+        if isinstance(v, RowBlocks):
+            if v.data.shape[0] % self.dp_n:
+                raise ValueError(
+                    f"RowBlocks of {v.data.shape[0]} rows do not split "
+                    f"into {self.dp_n} equal blocks")
+            data = jax.device_put(v.data, sh)
+            return data, (v.rows if v.rows < data.shape[0] else None)
+        arr = jnp.asarray(v)
+        n = int(arr.shape[0])
+        if n % self.dp_n == 0:
+            return jax.device_put(arr, sh), None
+        blk = -(-n // self.dp_n)
+        shape = (blk * self.dp_n,) + tuple(arr.shape[1:])
+        blocks = []
+        for dev, idx in sh.addressable_devices_indices_map(shape).items():
+            lo = idx[0].start or 0
+            part = arr[min(lo, n):min(lo + blk, n)]
+            if part.shape[0] < blk:
+                part = jnp.concatenate([part, jnp.zeros(
+                    (blk - part.shape[0],) + part.shape[1:], part.dtype)])
+            blocks.append(jax.device_put(part, dev))
+        return jax.make_array_from_single_device_arrays(shape, sh,
+                                                        blocks), n
+
+    def _place(self, inputs: dict):
         out = {}
         bag_limits: dict[str, int] = {}
         array_limits: dict[str, int] = {}
@@ -242,31 +327,18 @@ class DistributedProgram:
         for name, t in self.cp.program.params.items():
             v = inputs[name]
             if t.kind == "bag":
-                cols = v if isinstance(v, tuple) else (v,)
-                cols = tuple(jnp.asarray(c) for c in cols)
-                n = int(cols[0].shape[0])
-                pad = (-n) % self.dp_n
-                if pad:
-                    cols = tuple(jnp.concatenate(
-                        [c, jnp.zeros((pad,) + c.shape[1:], c.dtype)])
-                        for c in cols)
-                    bag_limits[name] = n
-                out[name] = tuple(
-                    jax.device_put(c, NamedSharding(self.mesh, P(self.dp)))
-                    for c in cols)
+                placed = [self._row_blocks(c)
+                          for c in (v if isinstance(v, tuple) else (v,))]
+                out[name] = tuple(c for c, _ in placed)
+                if placed[0][1] is not None:
+                    bag_limits[name] = placed[0][1]
             elif t.kind == "dim":
                 out[name] = int(v)
             elif t.kind in ("vector", "matrix", "map") \
                     and self._placed_oned(name):
-                arr = jnp.asarray(v)
-                n = int(arr.shape[0])
-                pad = (-n) % self.dp_n
-                if pad:
-                    arr = jnp.concatenate(
-                        [arr, jnp.zeros((pad,) + arr.shape[1:], arr.dtype)])
-                    array_limits[name] = n
-                out[name] = jax.device_put(
-                    arr, NamedSharding(self.mesh, P(self.dp)))  # row blocks
+                out[name], lim = self._row_blocks(v)   # row blocks
+                if lim is not None:
+                    array_limits[name] = lim
             else:
                 arr = jnp.asarray(v)
                 out[name] = jax.device_put(
@@ -284,23 +356,31 @@ class DistributedProgram:
         raise NotImplementedError(op)
 
     def _combine_shard(self, part, op: str, shard, dest_oned: bool,
-                       exchange: str = "psum_scatter"):
+                       exchange: str = "psum_scatter", dest: str = ""):
         """Cross-shard ⊕ of an unaligned partial: psum for a replicated
         destination; for a row-block destination the exchange the
         operator-selection subsystem chose — reduce-scatter (each shard
         receives its K/P rows) or allreduce + local slice (the only
         correct form for non-+ monoids, which have no reduce-scatter
-        primitive)."""
+        primitive).  Its ops carry the scope `Exchange:<dest>`."""
         F.site("dist.exchange", op=op, dest_oned=dest_oned,
                exchange=exchange)
-        if not dest_oned:
-            return self._psum(part, op)
-        if op == "+" and exchange == "psum_scatter":
-            return jax.lax.psum_scatter(part, self.dp, scatter_dimension=0,
-                                        tiled=True)
-        full = self._psum(part, op)
-        blk = full.shape[0] // self.dp_n
-        return jax.lax.dynamic_slice_in_dim(full, shard * blk, blk, axis=0)
+        with jax.named_scope(f"Exchange:{dest}"):
+            if not dest_oned:
+                return self._psum(part, op)
+            if op == "+" and exchange == "psum_scatter":
+                return jax.lax.psum_scatter(part, self.dp,
+                                            scatter_dimension=0, tiled=True)
+            full = self._psum(part, op)
+            blk = full.shape[0] // self.dp_n
+            return jax.lax.dynamic_slice_in_dim(full, shard * blk, blk,
+                                                axis=0)
+
+    def _gather(self, v, name: str):
+        """All-gather of a row-block array inside a round (the read
+        crosses shards), under the scope `Gather:<name>`."""
+        with jax.named_scope(f"Gather:{name}"):
+            return jax.lax.all_gather(v, self.dp, axis=0, tiled=True)
 
     # ------------------- rebalance rounds (ONED_VAR → ONED_ROW) ----------
     def _rebalance_local(self, x, shard, lim):
@@ -546,10 +626,17 @@ class DistributedProgram:
     def _call_round(self, fn, args, site_name, label):
         """Execute a traced round/fused program under the failure policy:
         the injection site fires per attempt, transients retry at this
-        level (bounded, backoff), and the wall time feeds the straggler
-        watchdog.  Capacity/deterministic errors re-raise — descending is
-        the caller's move (per-member bail for fused, the run() ladder
-        for rounds).
+        level (bounded, backoff), and the round's slowness feeds the
+        straggler watchdog.  Capacity/deterministic errors re-raise —
+        descending is the caller's move (per-member bail for fused, the
+        run() ladder for rounds).
+
+        `fn(*args)` returns once the round is enqueued, so its host time
+        says nothing of how long the devices take, and timing the round
+        to completion would cost a host sync per round.  The watchdog is
+        therefore fed the delay the fault layer reports at the round's
+        site (a `slow` fault), never an enqueue time: a fault-free run
+        never flags a straggler, whatever the clock reads.
 
         A flagged straggler additionally triggers speculative
         re-execution (DESIGN.md §13): at most ONE backup copy of the
@@ -557,34 +644,36 @@ class DistributedProgram:
         is cancelled.  Both copies run the same traced executable on the
         same operands, so adopting the faster one never changes results —
         speculation only buys back the tail latency a slow worker cost."""
+        slow = 0.0
+
         def attempt():
-            F.site(site_name, label=label)
+            nonlocal slow
+            slow += F.site(site_name, label=label)
             return fn(*args)
-        t0 = self.faults.clock()
-        out = F.run_with_retries(attempt, policy=self.policy,
-                                 ledger=self.faults, label=label)
-        dt = self.faults.clock() - t0
-        straggled = self.faults.note_time(label, dt)
+        with _span("repro.round"):
+            out = F.run_with_retries(attempt, policy=self.policy,
+                                     ledger=self.faults, label=label)
+        straggled = self.faults.note_time(label, slow)
         if straggled and self.speculative and label not in self._spec_done:
             self._spec_done.add(label)
             t1 = self.faults.clock()
             backup = fn(*args)        # no injection site: the backup runs
             #                           on a different (healthy) worker
             dt2 = self.faults.clock() - t1
-            if dt2 < dt:
-                saved = dt - dt2
+            if dt2 < slow:
+                saved = slow - dt2
                 self.faults.spec_saved_s += saved
                 self.faults.record(
                     "speculative", label,
                     f"backup won: {dt2 * 1e3:.1f}ms vs straggler "
-                    f"{dt * 1e3:.1f}ms (saved {saved * 1e3:.1f}ms); "
+                    f"{slow * 1e3:.1f}ms (saved {saved * 1e3:.1f}ms); "
                     f"straggler copy cancelled")
                 out = backup
             else:
                 self.faults.record(
                     "speculative", label,
-                    f"original finished first ({dt * 1e3:.1f}ms); backup "
-                    f"cancelled after {dt2 * 1e3:.1f}ms")
+                    f"original finished first ({slow * 1e3:.1f}ms); "
+                    f"backup cancelled after {dt2 * 1e3:.1f}ms")
         return out
 
     def _run_round(self, node, spec, env, limits, array_limits):
@@ -731,8 +820,7 @@ class DistributedProgram:
             for a in self.dp:
                 shard = shard * self.mesh.shape[a] + jax.lax.axis_index(a)
             for n in _gather:      # analysis: this read crosses shards
-                e2[n] = jax.lax.all_gather(e2[n], self.dp, axis=0,
-                                           tiled=True)
+                e2[n] = self._gather(e2[n], n)
             offs = {b: shard * e2[b][0].shape[0] for b in _bags}
             row_offs = {n: shard * e2[n].shape[0] for n in _local}
             axis_ov = {}
@@ -769,7 +857,7 @@ class DistributedProgram:
                     part_res = cp.executor.run_node(p, e2, ctx)
                     outs.append(self._combine_shard(
                         part_res, p.op, shard, dest_oned[p.dest],
-                        _exch.get(p.dest, "psum_scatter")))
+                        _exch.get(p.dest, "psum_scatter"), p.dest))
             return tuple(outs)
 
         # shard_map's replication check stays on unless a Pallas kernel
@@ -1093,9 +1181,6 @@ class DistributedProgram:
             for a in dp:
                 shard = shard * mesh_shape[a] + jax.lax.axis_index(a)
 
-            def to_global(v):
-                return jax.lax.all_gather(v, dp, axis=0, tiled=True)
-
             def to_block(v, nme):
                 blk = (shapes.get(nme) or dshapes[nme])[0] // dp_n
                 return jax.lax.dynamic_slice_in_dim(v, shard * blk, blk,
@@ -1103,7 +1188,7 @@ class DistributedProgram:
 
             def convert(e, nme, need):
                 e[nme] = to_block(e[nme], nme) if need == "block" \
-                    else to_global(e[nme])
+                    else self._gather(e[nme], nme)
 
             def run_body(e2):
                 for instr in instrs:
@@ -1117,7 +1202,7 @@ class DistributedProgram:
                         _t, m, g = instr
                         eu = dict(e2)
                         for n in g:
-                            eu[n] = to_global(eu[n])
+                            eu[n] = self._gather(eu[n], n)
                         ctx = ExecContext({}, node_lims, {}, arr_lims, {},
                                           frozenset())
                         e2[m.dest] = cp.executor.run_node(m, eu, ctx)
@@ -1128,7 +1213,7 @@ class DistributedProgram:
                         convert(e2, d, need)
                     eu = dict(e2)
                     for n in gathered:
-                        eu[n] = to_global(eu[n])
+                        eu[n] = self._gather(eu[n], n)
                     offs = {b: shard * eu[b][0].shape[0] for b in bagnames}
                     row_offs = {n: shard * eu[n].shape[0]
                                 for n in local_eff}
@@ -1169,7 +1254,7 @@ class DistributedProgram:
                             part_res = cp.executor.run_node(p, eu, ctx)
                             exchd = self._combine_shard(
                                 part_res, p.op, shard, oned,
-                                exch.get(p.dest, "psum_scatter"))
+                                exch.get(p.dest, "psum_scatter"), p.dest)
                             e2[p.dest] = COMBINE[p.op](prev, exchd)
                 return e2
 
@@ -1492,6 +1577,10 @@ class DistributedProgram:
 
     # ------------------------- entry -------------------------
     def run(self, inputs: dict) -> dict:
+        with _span("repro.run"):
+            return self._run(inputs)
+
+    def _run(self, inputs: dict) -> dict:
         """Distributed ladder (DESIGN.md §11/§12): fused → per-member
         rounds (inside _run_once, via _fused_bail) → REP-everything
         placements → the wrapped single-device program, whose own ladder
@@ -1506,8 +1595,8 @@ class DistributedProgram:
         just OOMed) and single-device concentrates the whole input on
         one device — both rungs are guaranteed re-OOMs.  A classified
         capacity error therefore descends straight to the chunked
-        out-of-core tier (core/chunked.py, halving tiles on repeat), or
-        to single-device only when out_of_core="off"."""
+        out-of-core tier (core/chunked.py, halving tiles on repeat); with
+        out_of_core="off" there is no rung below, and it surfaces."""
         try:
             return F.run_with_retries(
                 lambda: self._run_once(inputs),
@@ -1557,15 +1646,12 @@ class DistributedProgram:
                 return out
 
     def _descend_capacity(self, from_level: str, inputs: dict, ex) -> dict:
-        """Capacity exit: down the memory curve (DESIGN.md §12)."""
-        if self.cp.out_of_core != "off":
-            self.faults.descend(from_level, "chunked", ex)
-            out = self.cp._run_chunked(inputs, recovering=True)
-            return out
-        self.faults.descend(from_level, "single-device", ex)
-        out = self.cp.run(inputs)
-        self.faults.recover("single-device")
-        return out
+        """Capacity exit: down the memory curve (DESIGN.md §12), to the
+        chunked tier; with out_of_core="off" the error surfaces at once."""
+        if self.cp.out_of_core == "off":
+            raise ex
+        self.faults.descend(from_level, "chunked", ex)
+        return self.cp._run_chunked(inputs, recovering=True)
 
     def _run_once(self, inputs: dict, force_rep: bool = False) -> dict:
         env = {}

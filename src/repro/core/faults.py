@@ -166,9 +166,12 @@ class FaultInjector:
         self._rid_left = {id(s): s.times for s in self.specs
                           if s.rid is not None}
 
-    def fire(self, name: str, payload: dict) -> None:
+    def fire(self, name: str, payload: dict) -> float:
+        """Act on every spec matching this hit; returns the seconds of
+        the `slow` faults among them."""
         self.hits[name] += 1
         k = self.hits[name]
+        slow = 0.0
         for s in self.specs:
             if s.site != name:
                 continue
@@ -181,7 +184,10 @@ class FaultInjector:
                 continue
             self.fired.append({"site": name, "kind": s.kind, "hit": k,
                                "rid": s.rid})
+            if s.kind == "slow":
+                slow += s.delay_s
             self._act(s, name, k, payload)
+        return slow
 
     def _act(self, s: FaultSpec, name: str, k: int, payload: dict) -> None:
         if s.kind == "slow":
@@ -231,13 +237,13 @@ def inject(*specs: FaultSpec, clock=None):
         _ACTIVE = prev
 
 
-def site(name: str, **payload) -> None:
+def site(name: str, **payload) -> float:
     """The hook placed at every injection site.  Zero-cost when no
     injector is active; under jit/vmap it fires at TRACE time only
-    (python-level), which is exactly where compile faults live."""
+    (python-level), which is exactly where compile faults live.  Returns
+    the seconds of `slow` faults fired here (0.0 when none)."""
     inj = _ACTIVE
-    if inj is not None:
-        inj.fire(name, payload)
+    return 0.0 if inj is None else inj.fire(name, payload)
 
 
 # ---------------------------------------------------------------------------

@@ -178,7 +178,7 @@ class ExecContext:
                       RUN-TIME hot-key probe (op_select.probe_hot_fraction
                       + choose_salt) for this call's concrete key data.
                       The executor spreads each key over S sub-
-                      destinations (`key*S + salt`) and ⊕-folds the [K, S]
+                      destinations (`salt*K + key`) and ⊕-folds the [S, K]
                       partial back, so skewed keys stop serializing the
                       scatter.  Static pins (`SegmentReduce.salt`, set by
                       the planner from `PlanConfig.skew_salting`) take
@@ -708,8 +708,10 @@ class PlanExecutor:
         salt_s, salt_src = self._segment_salt(node, ctx, dest)
         if salt_s > 1:
             # hot-key salting: spread every key over S sub-destinations —
-            # `key*S + salt` with salt = global row index mod S — reduce a
-            # [K·S] partial, then ⊕-fold the [K, S] view back to [K].  The
+            # `salt*K + key` with salt = global row index mod S — reduce a
+            # [S·K] partial, then ⊕-fold the [S, K] view back to [K] (salt
+            # major: a [K, S] view puts S minor, which a TPU pads to 128
+            # lanes, 8.7 GB for K = 17M and S = 16).  The
             # fold over ALL S slots makes any salt assignment correct (⊕ is
             # associative-commutative); the global row index keeps the
             # assignment identical on one device and across shards.  Every
@@ -728,14 +730,14 @@ class PlanExecutor:
                     off = ctx.bag_offsets.get(lead.bag, 0)
                 salt = (off + jnp.arange(flat.shape[0], dtype=jnp.int32)) \
                     % salt_s
-                salted = jnp.where(flat < num, flat * salt_s + salt,
+                salted = jnp.where(flat < num, salt * num + flat,
                                    num * salt_s)
             with jax.named_scope("value"):
                 vflat = val.reshape(-1).astype(dest.dtype)
             part = self._segment_flat(backend, salted, vflat,
                                       num * salt_s, node.op)
             with jax.named_scope("salt_fold"):
-                part = REDUCE[node.op](part.reshape(num, salt_s), axis=1)
+                part = REDUCE[node.op](part.reshape(salt_s, num), axis=0)
             self.note(node, self.decisions.get(id(node), "")
                       + f" salt={salt_s}x[{salt_src}]")
             with jax.named_scope("combine"):

@@ -70,7 +70,7 @@ SEGMENT_CANDIDATES = {
 EXCHANGE_CANDIDATES = ("psum_scatter", "allreduce")
 CONTRACT_CANDIDATES = ("pallas-tiled", "unpack-einsum")
 
-# hot-key salting sub-destination factors (the S in key*S + salt); "none"
+# hot-key salting sub-destination factors (the S in salt*K + key); "none"
 # is always a candidate — it is the status quo
 SALT_FACTORS = (4, 8, 16)
 
@@ -389,7 +389,7 @@ class OpSelector:
     def choose_salt(self, *, n: int, k: int, op: str, nshards: int = 1,
                     hot_frac: float = 0.0) -> Decision:
         """Should this group-by salt its hot keys — spread each key over S
-        sub-destinations (`key*S + salt`) and ⊕-fold the [K, S] partial
+        sub-destinations (`salt*K + key`) and ⊕-fold the [S, K] partial
         back — and at which S?  Salting trades a k·S fold (and k·S partial
         memory) against the duplicate-update serialization a skewed key
         column induces in hardware scatters: a key holding fraction h of n

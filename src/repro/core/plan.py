@@ -207,7 +207,7 @@ class SegmentReduce:
     candidates: tuple[str, ...] = ("scatter",)
     shardings: Optional[dict] = None   # dist_analysis annotation
     salt: Optional[int] = None   # hot-key salting static hint: spread each
-    # key over S sub-destinations (key*S + salt), fold salts after; None =
+    # key over S sub-destinations (salt*K + key), fold salts after; None =
     # let op_select.choose_salt decide per shape class / runtime probe
     lineage = None   # RoundLineage recovery recipe (core/lineage.py, §13)
 

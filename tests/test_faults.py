@@ -450,17 +450,21 @@ assert "ladder-level-reached=chunked" in text
 assert "rounds->chunked" in text
 assert "rounds->rep" not in text
 
-# with out-of-core disabled, capacity falls back to the single-device
-# rung directly (still never rep)
+# with out-of-core disabled there is no rung down the memory curve:
+# capacity surfaces at once (never rep, never single-device, which would
+# concentrate every input on one device)
 dp = compile_distributed(fn, mesh, ("data",), mode="shardmap",
                          out_of_core="off")
 dp.faults.sleep = lambda s: None
 with F.inject(F.FaultSpec("dist.round_exec", "capacity", nth=1,
                           times=10**6)):
-    out = dp.run(ins)
-assert maxerr(out) < 1e-6
-assert dp.faults.level_reached == "single-device"
-assert "rounds->single-device" in dp.explain_faults()
+    try:
+        dp.run(ins)
+        raise AssertionError("capacity fault did not surface")
+    except F.CapacityFault:
+        pass
+assert dp.faults.counters["descend"] == 0
+assert dp.faults.level_reached == ""
 print("DIST_FAULTS_OK")
 """
 

@@ -62,17 +62,17 @@ def inputs(op, dtype):
 
 def argsort_gather_reference(keys, vals, dest, op, salt):
     """The group-by with argsort and two permutation gathers: dropped rows
-    to the sentinel segment, salted ids `key*S + row % S` folded back."""
+    to the sentinel segment, salted ids `(row % S) * K + key` folded back."""
     num = dest.shape[0]
     ids = jnp.where((keys >= 0) & (keys < num), keys, num)
     if salt > 1:
         row = jnp.arange(ids.shape[0], dtype=jnp.int32) % salt
-        ids = jnp.where(ids < num, ids * salt + row, num * salt)
+        ids = jnp.where(ids < num, row * num + ids, num * salt)
     order = jnp.argsort(ids, stable=True)
     part = SEG[op](vals[order], ids[order], num_segments=num * salt + 1,
                    indices_are_sorted=True)[:num * salt]
     if salt > 1:
-        part = REDUCE[op](part.reshape(num, salt), axis=1)
+        part = REDUCE[op](part.reshape(salt, num), axis=0)
     return COMBINE[op](dest, part)
 
 

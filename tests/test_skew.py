@@ -264,7 +264,7 @@ def run_case(nv, ne, op_select, salting, want, forbid=()):
         for f in forbid:
             assert f not in text, (stream, f, text)
 
-# large K, reduce-scatter exchange, salted rounds: the key*S+salt
+# large K, reduce-scatter exchange, salted rounds: the salt*K+key
 # sub-destinations fold back before the exchange, so the wire format
 # (dense [K] partial) is unchanged
 run_case(1 << 19, 4096, "force:psum_scatter", "force:4",
