@@ -93,6 +93,54 @@ REDUCE = {"+": jnp.sum, "*": jnp.prod, "min": jnp.min, "max": jnp.max}
 COMBINE = {"+": jnp.add, "*": jnp.multiply, "min": jnp.minimum,
            "max": jnp.maximum}
 
+
+def _operands(e) -> tuple:
+    """The operand expressions of a BinOp, UnOp or Call."""
+    if isinstance(e, BinOp):
+        return (e.lhs, e.rhs)
+    return (e.e,) if isinstance(e, UnOp) else tuple(e.args)
+
+
+def _apply(e, vals):
+    """A BinOp, UnOp or Call applied to its evaluated operands."""
+    if isinstance(e, BinOp):
+        return OPS[e.op](*vals)
+    if isinstance(e, UnOp):
+        return -vals[0] if e.op == "neg" else jnp.logical_not(vals[0])
+    return FNS[e.fn](*vals)
+
+
+def _premap_leaves(e, env, binding, gathers: list) -> bool:
+    """True when `e` is built of BinOp / UnOp / Call over constants,
+    scalars not bound to the iteration space and gathers, which it
+    appends to `gathers`."""
+    if isinstance(e, Const):
+        return True
+    if isinstance(e, Var):
+        return e.name not in binding and jnp.ndim(env[e.name]) == 0
+    if isinstance(e, (P.Gather, Get)):
+        gathers.append(e)
+        return True
+    return isinstance(e, (BinOp, UnOp, Call)) and all(
+        _premap_leaves(a, env, binding, gathers) for a in _operands(e))
+
+
+def _table(e, env):
+    """`e` over whole arrays: each gather stands for its array."""
+    if isinstance(e, (P.Gather, Get)):
+        return env[e.array]
+    if isinstance(e, (BinOp, UnOp, Call)):
+        return _apply(e, [_table(a, env) for a in _operands(e)])
+    return jnp.asarray(env[e.name] if isinstance(e, Var) else e.value)
+
+
+def _same(a, b) -> bool:
+    """Two row offsets or limits that are provably equal at trace time:
+    both absent, one traced value, or equal ints."""
+    return a is b or (isinstance(a, (int, np.integer))
+                      and isinstance(b, (int, np.integer)) and a == b)
+
+
 # host spans of run() (repro.run and its children, README "Tracing a
 # job"): written into a running profiler's trace on the device trace's
 # clock.  Constant names and no metadata, so with no profiler running a
@@ -277,6 +325,9 @@ class PlanExecutor:
         # operator/backend of each compiled node or per-shard round.
         self.decisions: dict = {}
         self._selector = selector
+        # group-by values read through one gather of a map (`_premap`),
+        # counted at trace time
+        self.premaps = 0
 
     @property
     def selector(self):
@@ -350,7 +401,12 @@ class PlanExecutor:
 
     # ---- expression evaluation over the iteration space ----
     def eval(self, e, env, ax: Axes, binding, masks: list,
-             ctx: ExecContext = _EMPTY_CTX):
+             ctx: ExecContext = _EMPTY_CTX, premap: list | None = None):
+        """Value of `e` over the iteration space; every gather appends its
+        inRange masks to `masks`.  With `premap` (a list: a group-by's
+        value), a subexpression whose gathers share one index is read as
+        one gather of its map over the arrays (`_premap`), and the names
+        of those arrays are appended to the list."""
         if isinstance(e, Const):
             return jnp.asarray(e.value)
         if isinstance(e, Var):
@@ -365,21 +421,7 @@ class PlanExecutor:
             from .tiles import TiledMatrix, unpack
             if isinstance(arr, TiledMatrix):   # §5 fallback: unpack on read
                 arr = unpack(arr)
-            # identity-traversal broadcast: statically marked eligible, and
-            # the runtime extents cover the array exactly (no gather).
-            # Padded (array_limits) and localized (row_offsets) arrays never
-            # qualify: their extents differ from the physical dim.
-            bc_ok = e.broadcast_ok if isinstance(e, P.Gather) else True
-            if bc_ok and len(e.idxs) == len(arr.shape) and \
-                    e.array not in ctx.row_offsets and \
-                    e.array not in ctx.array_limits and \
-                    all(isinstance(ix, Var) and ix.name in binding
-                        and binding[ix.name][0] == "range"
-                        and isinstance(binding[ix.name][2], int)
-                        and binding[ix.name][2] == 0
-                        and ax.extent[ix.name] == d
-                        for ix, d in zip(e.idxs, arr.shape)) and \
-                    len({ix.name for ix in e.idxs}) == len(e.idxs):
+            if self._broadcasts(e, arr, ax, binding, ctx):
                 names = [ix.name for ix in e.idxs]
                 shape = [1] * len(ax.order)
                 perm_src = sorted(names, key=ax.pos)
@@ -389,43 +431,112 @@ class PlanExecutor:
                 return jnp.reshape(a2, shape)
             idxs = [self.eval(i, env, ax, binding, masks, ctx)
                     for i in e.idxs]
-            off = ctx.row_offsets.get(e.array)
-            lim = ctx.array_limits.get(e.array)
-            cooked = []
-            for dim_i, (d, ix) in enumerate(zip(arr.shape, idxs)):
-                ix = jnp.asarray(ix, jnp.int32)
-                if dim_i == 0:
-                    if lim is not None:     # logical bound, global coords
-                        masks.append(ix < lim)
-                    if off is not None:     # localize to the shard's block
-                        ix = ix - off
-                # uint32 reinterpretation: negatives wrap past any dim, so
-                # ONE unsigned compare is the whole inRange check
-                # ((ix >= 0) & (ix < d)) and the gather indexes unsigned
-                iu = ix.astype(jnp.uint32)
-                masks.append(iu < jnp.uint32(d))
-                cooked.append(iu)
-            # clip-mode gather on the unsigned indices: out-of-range rows
-            # read a clamped row, and §3.4 empty-bag semantics live in the
-            # recorded inRange MASK, which every consumer applies — the
-            # gathered value at a dropped row is never observable.  Clamp
-            # is one fusable op; a fill-mode gather would add a
-            # compare+select pair per gather, measured ~20% slower on the
-            # scatter-fed group-by path (pagerank's inner loop).
-            if len(cooked) == 1:
-                return jnp.take(arr, cooked[0], axis=0, mode="clip")
-            return arr.at[tuple(jnp.broadcast_arrays(*cooked))].get(
-                mode="clip")
-        if isinstance(e, BinOp):
-            return OPS[e.op](self.eval(e.lhs, env, ax, binding, masks, ctx),
-                             self.eval(e.rhs, env, ax, binding, masks, ctx))
-        if isinstance(e, UnOp):
-            v = self.eval(e.e, env, ax, binding, masks, ctx)
-            return -v if e.op == "neg" else jnp.logical_not(v)
-        if isinstance(e, Call):
-            return FNS[e.fn](*[self.eval(a, env, ax, binding, masks, ctx)
-                               for a in e.args])
+            return self._gather(arr, e.array, idxs, masks, ctx)
+        if isinstance(e, (BinOp, UnOp, Call)):
+            if premap is not None:
+                v = self._premap(e, env, ax, binding, masks, ctx, premap)
+                if v is not None:
+                    return v
+            return _apply(e, [self.eval(a, env, ax, binding, masks, ctx,
+                                        premap) for a in _operands(e)])
         raise RejectionError(f"cannot execute expression {e}")
+
+    def _broadcasts(self, e, arr, ax, binding, ctx) -> bool:
+        """Identity-traversal broadcast: statically marked eligible, and
+        the runtime extents cover the array exactly (no gather).  Padded
+        (array_limits) and localized (row_offsets) arrays never qualify:
+        their extents differ from the physical dim."""
+        bc_ok = e.broadcast_ok if isinstance(e, P.Gather) else True
+        return bc_ok and len(e.idxs) == len(arr.shape) and \
+            e.array not in ctx.row_offsets and \
+            e.array not in ctx.array_limits and \
+            all(isinstance(ix, Var) and ix.name in binding
+                and binding[ix.name][0] == "range"
+                and isinstance(binding[ix.name][2], int)
+                and binding[ix.name][2] == 0
+                and ax.extent[ix.name] == d
+                for ix, d in zip(e.idxs, arr.shape)) and \
+            len({ix.name for ix in e.idxs}) == len(e.idxs)
+
+    def _gather(self, arr, name, idxs, masks, ctx):
+        """`arr` read at the evaluated indices `idxs`, with the array
+        `name`'s row offset and logical limit; appends the inRange masks."""
+        off = ctx.row_offsets.get(name)
+        lim = ctx.array_limits.get(name)
+        cooked = []
+        for dim_i, (d, ix) in enumerate(zip(arr.shape, idxs)):
+            ix = jnp.asarray(ix, jnp.int32)
+            if dim_i == 0:
+                if lim is not None:     # logical bound, global coords
+                    masks.append(ix < lim)
+                if off is not None:     # localize to the shard's block
+                    ix = ix - off
+            # uint32 reinterpretation: negatives wrap past any dim, so
+            # ONE unsigned compare is the whole inRange check
+            # ((ix >= 0) & (ix < d)) and the gather indexes unsigned
+            iu = ix.astype(jnp.uint32)
+            masks.append(iu < jnp.uint32(d))
+            cooked.append(iu)
+        # clip-mode gather on the unsigned indices: out-of-range rows
+        # read a clamped row, and §3.4 empty-bag semantics live in the
+        # recorded inRange MASK, which every consumer applies — the
+        # gathered value at a dropped row is never observable.  Clamp
+        # is one fusable op; a fill-mode gather would add a
+        # compare+select pair per gather, measured ~20% slower on the
+        # scatter-fed group-by path (pagerank's inner loop).
+        if len(cooked) == 1:
+            return jnp.take(arr, cooked[0], axis=0, mode="clip")
+        return arr.at[tuple(jnp.broadcast_arrays(*cooked))].get(
+            mode="clip")
+
+    def _premap(self, e, env, ax, binding, masks, ctx, premapped: list):
+        """Gathers that share one index, read as one gather of a map:
+        `A[i] / B[i]` as `(A / B)[i]`.  `e` qualifies when it is built of
+        BinOp / UnOp / Call over constants, scalars (names not bound to
+        the iteration space) and gathers of at least two distinct arrays,
+        all at one index tuple; the arrays have one shape, the same row
+        offset and logical limit, none arrives §5-packed and none is read
+        by identity broadcast.  Each row then applies the same elementwise
+        ops to the same values, bit for bit, and a clamped or masked row
+        reads one row of every array either way.  The map is evaluated
+        once over the arrays (the table, scope `premap`) and gathered with
+        one set of indices and inRange masks.  Only where the table holds
+        no more elements than the index has rows: a chunk of rows, or a
+        small call, keeps one gather per array.  None when `e` does not
+        qualify."""
+        from .tiles import TiledMatrix
+        gathers: list = []
+        if not _premap_leaves(e, env, binding, gathers):
+            return None
+        names = list(dict.fromkeys(g.array for g in gathers))
+        idxs = gathers[0].idxs if gathers else ()
+        if len(names) < 2 or any(g.idxs != idxs for g in gathers):
+            return None
+        arrs = [env[n] for n in names]
+        if any(isinstance(a, TiledMatrix) for a in arrs) or any(
+                self._broadcasts(g, env[g.array], ax, binding, ctx)
+                for g in gathers):
+            return None
+        shape = jnp.shape(arrs[0])
+        if any(jnp.shape(a) != shape
+               or not _same(ctx.row_offsets.get(n),
+                            ctx.row_offsets.get(names[0]))
+               or not _same(ctx.array_limits.get(n),
+                            ctx.array_limits.get(names[0]))
+               for n, a in zip(names, arrs)):
+            return None
+        ix_masks: list = []
+        ix = [self.eval(i, env, ax, binding, ix_masks, ctx) for i in idxs]
+        rows = int(np.prod(jnp.broadcast_shapes(*[jnp.shape(i)
+                                                  for i in ix])))
+        if int(np.prod(shape)) > rows:
+            return None
+        masks.extend(ix_masks)
+        with jax.named_scope("premap"):
+            table = _table(e, env)
+        self.premaps += 1
+        premapped.extend(names)
+        return self._gather(table, names[0], ix, masks, ctx)
 
     def _mask(self, conds, env, ax, binding, masks,
               ctx: ExecContext = _EMPTY_CTX):
@@ -693,9 +804,11 @@ class PlanExecutor:
             keys = [self.eval(k, env, ax, binding, masks, ctx)
                     for k in node.keys]
         shape = ax.shape()
+        premapped: list = []
         with jax.named_scope("value"):
             val = jnp.broadcast_to(
-                self.eval(node.value, env, ax, binding, masks, ctx), shape)
+                self.eval(node.value, env, ax, binding, masks, ctx,
+                          premapped), shape)
         with jax.named_scope("keys"):
             m = self._mask(conds, env, ax, binding, masks, ctx)
             kk = [jnp.broadcast_to(jnp.asarray(k, jnp.int32), shape)
@@ -706,6 +819,12 @@ class PlanExecutor:
             n_rows *= d_
         backend = self._segment_backend(node, n_rows, dest)
         salt_s, salt_src = self._segment_salt(node, ctx, dest)
+        tag = self.decisions.get(id(node), "")
+        if salt_s > 1:
+            tag += f" salt={salt_s}x[{salt_src}]"
+        if premapped:
+            tag += " premap=" + ",".join(premapped)
+        self.note(node, tag)
         if salt_s > 1:
             # hot-key salting: spread every key over S sub-destinations —
             # `salt*K + key` with salt = global row index mod S — reduce a
@@ -738,8 +857,6 @@ class PlanExecutor:
                                       num * salt_s, node.op)
             with jax.named_scope("salt_fold"):
                 part = REDUCE[node.op](part.reshape(salt_s, num), axis=0)
-            self.note(node, self.decisions.get(id(node), "")
-                      + f" salt={salt_s}x[{salt_src}]")
             with jax.named_scope("combine"):
                 return COMBINE[node.op](
                     dest, part.reshape(dest.shape).astype(dest.dtype))
