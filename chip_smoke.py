@@ -11,8 +11,8 @@ kmeans_step (2^21 points, K=64) and an 8192² matrix product, dense and with
 the left operand §5-packed at 50% tile density (the Pallas tile kernel);
 then a `PlanServer` answering concurrent pagerank and group_by requests.
 Four chips: pagerank and group_by through `compile_distributed` on a (4,)
-"data" mesh in shardmap and gspmd mode, against the same programs on
-device 0.  Nothing else runs in that mode.
+"data" mesh, against the same programs on device 0.  Nothing else runs in
+that mode.
 
 Every input is generated from --seed in numpy and every output is checked
 against a plain numpy float64 reference written here, to 1e-4 relative
@@ -393,8 +393,8 @@ def phase_serve(sizes: Sizes, seed: int) -> dict:
 
 
 def phase_distributed(sizes: Sizes, seed: int, ndev: int) -> list:
-    """pagerank and group_by over a (ndev,) "data" mesh, shardmap and
-    gspmd, against the single-device program on device 0 and numpy."""
+    """pagerank and group_by over a (ndev,) "data" mesh, against the
+    single-device program on device 0 and numpy."""
     from repro.core import compile_program
     from repro.core.distributed import compile_distributed
     from repro.core.programs import group_by, pagerank
@@ -416,32 +416,31 @@ def phase_distributed(sizes: Sizes, seed: int, ndev: int) -> list:
             close(f"{name}.single.{k}", single[k], r)
         log(f"[{name}.single] device 0  first run {first_s} s  warm run "
             f"{warm_s} s")
-        for mode in ("shardmap", "gspmd"):
-            label = f"{name}.{mode}"
-            dp = compile_distributed(fn, mesh, ("data",), mode=mode,
-                                     autotune_cache=NO_AUTOTUNE_CACHE)
-            placed, _, _ = dp.place(ins)
-            devs = {s.device for s in placed[bag][0].addressable_shards}
-            if len(devs) != ndev:
-                raise SmokeFailure(f"{label}: bag spans {len(devs)} devices")
-            _, first_s = _timed(lambda: dp.run(ins))
-            out, warm_s = _timed(lambda: dp.run(ins))
-            check_clean(label, dp.cp)
-            rounds = check_rounds(label, dp)
-            if name == "pagerank" and mode == "shardmap" and (
-                    "FusedRound" not in rounds
-                    or "on-device lax.while_loop" not in rounds):
-                raise SmokeFailure(f"{label}: loop not fused on device\n"
-                                   f"{rounds}")
-            err = max(max(close(f"{label}.{k}", out[k], r),
-                          close(f"{label}.{k} vs single", out[k], single[k]))
-                      for k, r in ref.items())
-            log(f"[{label}] {ndev} devices  first run {first_s} s  warm "
-                f"run {warm_s} s  max rel err {err}")
-            for ln in rounds.splitlines():
-                log(f"[{label}]   {ln}")
-            recs.append(dict(program=label, first_run_s=first_s,
-                             warm_run_s=warm_s, max_rel_err=err))
+        label = f"{name}.shardmap"
+        dp = compile_distributed(fn, mesh, ("data",),
+                                 autotune_cache=NO_AUTOTUNE_CACHE)
+        placed, _, _ = dp.place(ins)
+        devs = {s.device for s in placed[bag][0].addressable_shards}
+        if len(devs) != ndev:
+            raise SmokeFailure(f"{label}: bag spans {len(devs)} devices")
+        _, first_s = _timed(lambda: dp.run(ins))
+        out, warm_s = _timed(lambda: dp.run(ins))
+        check_clean(label, dp.cp)
+        rounds = check_rounds(label, dp)
+        if name == "pagerank" and (
+                "FusedRound" not in rounds
+                or "on-device lax.while_loop" not in rounds):
+            raise SmokeFailure(f"{label}: loop not fused on device\n"
+                               f"{rounds}")
+        err = max(max(close(f"{label}.{k}", out[k], r),
+                      close(f"{label}.{k} vs single", out[k], single[k]))
+                  for k, r in ref.items())
+        log(f"[{label}] {ndev} devices  first run {first_s} s  warm "
+            f"run {warm_s} s  max rel err {err}")
+        for ln in rounds.splitlines():
+            log(f"[{label}]   {ln}")
+        recs.append(dict(program=label, first_run_s=first_s,
+                         warm_run_s=warm_s, max_rel_err=err))
     return recs
 
 

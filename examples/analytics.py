@@ -46,7 +46,7 @@ def main():
     print(cp.explain())        # operator + inferred sharding per statement
     sharded = [a for a, d in cp.dists.items() if d >= Dist.ONED_ROW]
     print(f"\ndense arrays sharded (not replicated): {sorted(sharded)}\n")
-    dp = compile_distributed(cp, mesh, ("data",), mode="shardmap")
+    dp = compile_distributed(cp, mesh, ("data",))
     ranks = np.asarray(dp.run(ins)["P"])
     single = np.asarray(cp.run(ins)["P"])
     # the operator-selection subsystem (DESIGN.md §8) resolved each
@@ -72,7 +72,7 @@ def main():
                D=np.zeros((npts, K)), MinD=np.full(npts, 1e30),
                Cl=np.zeros(npts), SX=np.zeros(K), SY=np.zeros(K),
                CN=np.zeros(K), NX=np.zeros(K), NY=np.zeros(K))
-    ck = compile_distributed(kmeans_step, mesh, ("data",), mode="gspmd")
+    ck = compile_distributed(kmeans_step, mesh, ("data",))
     print(ck.cp.estimate_memory(ins).summary(None))
     out = ck.run(ins)
     print("kmeans new centroids x:",

@@ -1,6 +1,6 @@
 """The one home of JAX's persistent compilation cache.
 
-Entry points (chip_smoke.py, benchmarks/run.py, the examples) call
+Entry points (chip_smoke.py, bench/run.py, the examples) call
 `enable()` once before they compile anything; importing the library never
 does.  If `JAX_COMPILATION_CACHE_DIR` is set, JAX already reads it and
 nothing else is configured.  Otherwise the cache lives at the fixed path

@@ -11,7 +11,7 @@
 #     filtered arrays with variable live blocks, rebalanced to ONED_ROW
 #     only where readers need it — printed by CompiledProgram.explain())
 #   → lower.PlanExecutor (plan nodes → JAX, runtime guards + fallbacks)
-#   → distributed (shard_map / gspmd execution of the same plan over a mesh;
+#   → distributed (shard_map execution of the same plan over a mesh;
 #     bags AND inferred-ONED_ROW dense arrays shard as row blocks)
 from .analysis import check
 from .chunked import ChunkLoop, ChunkRunner, chunk_plan, choose_chunk_rows

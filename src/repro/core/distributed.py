@@ -2,12 +2,13 @@
 the paper's DISC backend, retargeted from Spark shuffles to TPU collectives
 (DESIGN.md §4, §6).
 
-Both modes consume the SAME physical plan (CompiledProgram.plan) through
-the public executor interface; bag offsets/limits and the dense-array
-analogues (row offsets, logical row limits, axis overrides) are plan
-parameters (lower.ExecContext), not lowerer state.
+Execution consumes the SAME physical plan (CompiledProgram.plan) as the
+single-device run(), through the public executor interface; bag
+offsets/limits and the dense-array analogues (row offsets, logical row
+limits, axis overrides) are plan parameters (lower.ExecContext), not
+lowerer state.
 
-* ``shardmap`` (paper-faithful operator mapping): bags shard over the dp
+* shard_map (paper-faithful operator mapping): bags shard over the dp
   axes, and — per the distribution-analysis pass (dist_analysis.py) —
   dense arrays inferred ONED_ROW/TWOD_BLOCK shard as contiguous dim-0 row
   blocks too, instead of replicating.  Each plan node runs as one of:
@@ -80,10 +81,6 @@ parameters (lower.ExecContext), not lowerer state.
   fully-replicated body short-circuits through the single-device
   lax.while_loop).  Guard failures fall back to per-member rounds;
   fusion never changes results, only dispatch.
-
-* ``gspmd``: the single-device plan executed on sharded inputs; XLA's
-  SPMD partitioner inserts the collectives.  Works for every program,
-  including range-driven contractions (matmul → partitioned einsum).
 
 Bags AND ONED_ROW dense arrays whose dim-0 length is not divisible by the
 shard count are PADDED with zero rows to the next multiple, block by block:
@@ -165,6 +162,10 @@ class RowBlocks:
 class DistributedProgram:
     def __init__(self, cp: CompiledProgram, mesh, dp_axes=("data",),
                  mode: str = "shardmap", shard_dense: bool = True):
+        if mode != "shardmap":
+            raise ValueError(f"unknown distributed mode {mode!r}: "
+                             f"only 'shardmap' is supported (gspmd was "
+                             f"removed)")
         self.cp = cp
         self.mesh = mesh
         self.dp = tuple(dp_axes)
@@ -1688,11 +1689,7 @@ class DistributedProgram:
             cts, fac = self._shard_counts(int(shp[0]),
                                           array_limits.get(name))
             self._balance[name] = (cts, fac, kind)
-        if self.mode == "gspmd":
-            self.cp.execute(env, bag_limits=limits,
-                            array_limits=array_limits)
-        else:
-            self._exec_shardmap(self.cp.plan, env, limits, array_limits)
+        self._exec_shardmap(self.cp.plan, env, limits, array_limits)
         out = {}
         for n in self.cp.program.outputs:
             v = env[n]

@@ -17,17 +17,17 @@ Node → JAX mapping:
                   masks or scatters (guard: extents cover the destination
                   exactly) — else the general MapExpr path
   Scatter         .at[].set at computed keys, OOB rows dropped
-  SegmentReduce   one of four backends, chosen at trace time by the
+  SegmentReduce   one of three backends, chosen at trace time by the
                   operator-selection subsystem (op_select.py, DESIGN.md
                   §8) from the node's candidate set: native scatter-⊕
                   with drop semantics (no identity segment array, no
                   index flattening), sort-based jax.ops.segment_⊕ over
-                  sorted keys, one-hot dot_general on the MXU, or the
-                  Pallas blocked one-hot kernel.  `backend="auto"`
-                  resolves via the cost model / autotune cache against
-                  the concrete (N, K, D, dtype, dest-sharding) shape
-                  class; a concrete backend name pins the choice.  The
-                  resolved decision is recorded (explain() prints it)
+                  sorted keys, or the Pallas blocked one-hot kernel.
+                  `backend="auto"` resolves via the cost model /
+                  autotune cache against the concrete (N, K, D, dtype,
+                  dest-sharding) shape class; a concrete backend name
+                  pins the choice.  The resolved decision is recorded
+                  (explain() prints it)
   AxisReduce      ⊕-reduce over contracted axes (Rule 17: no shuffle); a
                   `product` certificate contracts via jnp.einsum instead of
                   the dense grid (same operator, MXU materialization)
@@ -44,8 +44,8 @@ Node → JAX mapping:
   Fused           parts executed against the shared iteration space
 
 Distributed execution passes bag offsets/limits through ExecContext — plan
-parameters, not executor state — so the same plan serves single-device,
-shard_map and gspmd backends (see distributed.py).
+parameters, not executor state — so the same plan serves single-device
+and shard_map execution (see distributed.py).
 
 The compiled program is a pure function dict->dict and is jit-compatible
 (dims must be python ints: they define static shapes).
@@ -861,7 +861,7 @@ class PlanExecutor:
                 return COMBINE[node.op](
                     dest, part.reshape(dest.shape).astype(dest.dtype))
         if backend != "scatter":
-            # flattened-segment backends (sort / onehot / pallas): ravel
+            # flattened-segment backends (sort / pallas): ravel
             # the key tuple against the physical dims, route every dropped
             # row (OOB key, negative key, padded row, failed condition) to
             # the sentinel segment `num`, reduce into a [num] partial and
@@ -948,23 +948,6 @@ class PlanExecutor:
             with jax.named_scope("reduce"):
                 return seg(vals, ids, num_segments=num + 1,
                            indices_are_sorted=True)[:num]
-        if backend == "onehot":
-            # group-by as matmul: [N, num] one-hot × [N] values on the
-            # MXU.  Integer values take the exact-int path (int32
-            # accumulation); floats accumulate in f32.  Sentinel rows'
-            # VALUES must be zeroed too: their one-hot row is all zeros,
-            # but 0 × inf/NaN would still contaminate the dot — dropped
-            # rows may carry non-finite values (e.g. a condition guarding
-            # a division), and drop semantics say they contribute nothing
-            acc = vals.dtype if jnp.issubdtype(vals.dtype, jnp.integer) \
-                else jnp.float32
-            with jax.named_scope("reduce"):
-                vals = jnp.where(ids == num, jnp.zeros((), vals.dtype), vals)
-                oh = (ids[:, None] == jnp.arange(num)[None, :]).astype(acc)
-                return jax.lax.dot_general(
-                    vals.astype(acc)[None, :], oh, (((1,), (0,)), ((), ())),
-                    precision=xla_f32_dot(),
-                    preferred_element_type=acc)[0]
         if backend == "pallas":
             from ..kernels import ops as kops
             with jax.named_scope("reduce"):

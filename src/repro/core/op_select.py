@@ -8,17 +8,13 @@ reduction (rows N, segments K, value columns D), the dtype, the platform,
 and — distributed — on where the destination lives and how many rows each
 shard holds.  This module owns that choice.  Candidate backends:
 
-  scatter   native scatter-⊕ with drop semantics (dest.at[keys].⊕); the
-            all-rounder on CPU, serialized per duplicate key on TPU
+  scatter   native scatter-⊕ with drop semantics (dest.at[keys].⊕);
+            serialized per duplicate key on TPU
   sort      one key/value sort, then jax.ops.segment_⊕ with
-            indices_are_sorted — the classic GPU shape; loses on CPU
-            (measured, see BENCH_kernels.json)
-  onehot    [N, K] one-hot × [N, D] values on the MXU via dot_general —
-            group-by as matmul; wins for small K everywhere (measured ~6x
-            over scatter at K=16 even on CPU BLAS)
+            indices_are_sorted — the TPU's choice for large K
   pallas    the blocked Pallas one-hot-MXU kernel (kernels/segment_reduce)
-            — the TPU-native form; interpret-mode (CPU) cost is python-
-            level, so the model only picks it on a real TPU backend
+            — the TPU's choice for small K; interpret-mode (CPU) cost is
+            python-level, so the model only picks it on a real TPU backend
 
 plus the distributed-exchange choice for a sharded group-by round
 (`psum_scatter` vs allreduce+slice) and the §5 packed-matmul choice
@@ -27,10 +23,8 @@ plus the distributed-exchange choice for a sharded group-by round
 Two modes, one interface:
 
   cost      (default) an analytical model over shape classes — abstract
-            per-element costs per platform, CPU constants calibrated
-            against measurement (benchmarks/kernels_bench.py), TPU/GPU
-            constants first-principles estimates.  Deterministic: same
-            shapes → same decision (golden-testable).
+            per-element costs per platform.  Deterministic: same shapes →
+            same decision (golden-testable).
   autotune  measure every candidate once per SHAPE CLASS ((N, K, D)
             bucketed to powers of two, dtype, op, dest sharding) on the
             first encounter, persist the winner to an on-disk cache
@@ -57,11 +51,11 @@ from typing import Optional
 
 import numpy as np
 
-# candidate sets per monoid ⊕ (correctness, not preference: onehot only
-# sums; sort covers every monoid via jax.ops.segment_*; pallas does + via
-# the MXU dot and min/max via the one-hot select path)
+# candidate sets per monoid ⊕ (correctness, not preference: sort covers
+# every monoid via jax.ops.segment_*; pallas does + via the MXU dot and
+# min/max via the one-hot select path)
 SEGMENT_CANDIDATES = {
-    "+": ("scatter", "sort", "onehot", "pallas"),
+    "+": ("scatter", "sort", "pallas"),
     "min": ("scatter", "sort", "pallas"),
     "max": ("scatter", "sort", "pallas"),
     "*": ("scatter", "sort"),
@@ -98,28 +92,28 @@ class Decision:
 # the analytical cost model
 # ---------------------------------------------------------------------------
 # Abstract cost in µs: fixed dispatch overhead + per-element rates.  The
-# cpu row is CALIBRATED against measurement on the container (see
-# BENCH_kernels.json; scatter ~0.12µs/row, onehot ~0.002µs/cell, argsort
-# ~0.05µs/(row·log₂N), Pallas interpret mode is python-level — modeled as
-# a prohibitive fixed cost so it is never cost-picked off-TPU).  tpu/gpu
-# rows are first-principles estimates (scatter serializes on duplicate
+# cpu row decides what the tests run off-TPU: scatter at their sizes,
+# with Pallas interpret mode (python-level) priced out by a large fixed
+# cost, and no salting (dup_row=0).  The tpu row picks what the chip
+# benchmark's cells run (sort at large K, pallas at small K); it and the
+# gpu row are first-principles estimates (scatter serializes on duplicate
 # keys; the MXU streams one-hot cells at matmul rate) — autotune mode
 # replaces them with measurement the first time a class is seen on the
 # real hardware.
 
 _COSTS = {
     "cpu": dict(fixed=60.0, scatter_row=0.12, sort_row=0.05,
-                onehot_cell=0.002, pallas_cell=0.002, pallas_fixed=2e5,
+                pallas_cell=0.002, pallas_fixed=2e5,
                 coll_row=0.004, coll_fixed=400.0, dest_shard_fixed=1500.0,
                 tile_mxu=math.inf, einsum_cell=4e-5, unpack_cell=1.5e-3,
                 dup_row=0.0, salt_fold=0.004),
     "tpu": dict(fixed=5.0, scatter_row=1.0, sort_row=0.01,
-                onehot_cell=2e-4, pallas_cell=1.2e-5, pallas_fixed=30.0,
+                pallas_cell=1.2e-5, pallas_fixed=30.0,
                 coll_row=1e-4, coll_fixed=10.0, dest_shard_fixed=5.0,
                 tile_mxu=1.5e-5, einsum_cell=1.5e-5, unpack_cell=2e-4,
                 dup_row=1.0, salt_fold=2e-4),
     "gpu": dict(fixed=10.0, scatter_row=0.05, sort_row=0.008,
-                onehot_cell=3e-4, pallas_cell=math.inf, pallas_fixed=math.inf,
+                pallas_cell=math.inf, pallas_fixed=math.inf,
                 coll_row=2e-4, coll_fixed=20.0, dest_shard_fixed=50.0,
                 tile_mxu=math.inf, einsum_cell=2e-5, unpack_cell=3e-4,
                 dup_row=0.01, salt_fold=3e-4),
@@ -139,8 +133,6 @@ def _segment_cost(c: dict, backend: str, n: int, k: int, d: int) -> float:
     if backend == "sort":
         return c["fixed"] + c["sort_row"] * n * (math.log2(max(2, n)) +
                                                  max(1, d))
-    if backend == "onehot":
-        return c["fixed"] + c["onehot_cell"] * nkd
     if backend == "pallas":
         return c["pallas_fixed"] + c["pallas_cell"] * nkd
     return math.inf
@@ -172,7 +164,6 @@ def _hot_bucket(hot_frac: float) -> int:
 # autotune measurement (standalone impls mirroring the executor backends)
 # ---------------------------------------------------------------------------
 
-_MEASURE_CELL_CAP = 2e8     # onehot materializes N×K: skip beyond this
 _MEASURE_INTERP_CAP = 1e7   # pallas interpret mode is python-level: skip
 #                             big classes off-TPU instead of stalling the
 #                             first autotuned run for minutes
@@ -187,8 +178,6 @@ def _measure_segment(backend: str, n: int, k: int, d: int, op: str,
     import jax.numpy as jnp
 
     cells = n * k * max(1, d)
-    if backend == "onehot" and cells > _MEASURE_CELL_CAP:
-        return math.inf
     if backend == "pallas" and jax.default_backend() != "tpu" \
             and cells > _MEASURE_INTERP_CAP:
         return math.inf
@@ -215,18 +204,6 @@ def _measure_segment(backend: str, n: int, k: int, d: int, op: str,
             return COMBINE[op](de, _seg(vs, i, num_segments=de.size,
                                         indices_are_sorted=True)
                                .reshape(de.shape))
-        fn = jax.jit(fn)
-    elif backend == "onehot":
-        def fn(de, i, v, _k=k):
-            acc = v.dtype if jnp.issubdtype(v.dtype, jnp.integer) \
-                else jnp.float32
-            oh = (i[:, None] == jnp.arange(_k)[None, :]).astype(acc)
-            v2 = v[:, None] if v.ndim == 1 else v
-            part = jax.lax.dot_general(oh, v2.astype(acc),
-                                       (((0,), (0,)), ((), ())),
-                                       preferred_element_type=acc)
-            part = part[:, 0] if v.ndim == 1 else part
-            return de + part.astype(de.dtype)
         fn = jax.jit(fn)
     elif backend == "pallas":
         from ..kernels import ops as kops
@@ -360,7 +337,7 @@ class OpSelector:
         if self.forced is not None and self.forced in cands:
             return Decision(self.forced, "forced")
         # a forced backend the candidate set does not admit (e.g.
-        # force:onehot on a min-group-by) falls through to the model —
+        # force:pallas on a *-group-by) falls through to the model —
         # pinning only applies where the pin is correct
         key = self.segment_class(n, k, d, op, str(dtype), dest_dist)
         hit = self._cache.get(key)

@@ -39,7 +39,7 @@ contract (see DESIGN.md):
                                 the finished plan; annotation-only
                                 (dist_analysis.py, DESIGN.md §6)
  10. operator-selection         backend CANDIDATE sets on SegmentReduce
-                                (scatter / sort / onehot / pallas) and the
+                                (scatter / sort / pallas) and the
                                 contraction nodes; the concrete choice is
                                 resolved at trace time by the cost-model /
                                 autotune selector (op_select.py, DESIGN.md

@@ -191,11 +191,11 @@ class SegmentReduce:
     """Group-by on computed keys → segment-⊕ into the destination index
     space (the paper's shuffle).  `candidates` is the backend candidate
     set the operator-selection pass attached (op_select.py, DESIGN.md §8):
-    scatter-⊕ / sort-based segment reduce / one-hot dot_general / the
-    Pallas MXU kernel.  `backend="auto"` defers the choice to the
-    cost-model/autotune selector at trace time (shapes are known there);
-    any concrete name pins it.  The executor records the resolved choice —
-    explain() prints it as a `selected:` line after a run."""
+    scatter-⊕ / sort-based segment reduce / the Pallas MXU kernel.
+    `backend="auto"` defers the choice to the cost-model/autotune selector
+    at trace time (shapes are known there); any concrete name pins it.
+    The executor records the resolved choice — explain() prints it as a
+    `selected:` line after a run."""
     stmt: Any
     space: IterSpace
     reads: frozenset

@@ -3,7 +3,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 """Multi-pod dry-run: lower + compile every (arch x shape) cell on the
 production mesh (16x16 single-pod / 2x16x16 multi-pod), print
-memory_analysis / cost_analysis, and derive roofline terms from the
+memory_analysis / cost_analysis, and the FLOP / byte counts of the
 partitioned HLO (trip-count-expanded; see hlo_analysis.py).
 
   PYTHONPATH=src python -m repro.launch.dryrun --arch llama3-8b --shape train_4k
@@ -31,7 +31,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir: str,
     from ..models import get_model
     from ..train.step import make_train_step
     from ..serve.step import make_decode_step, make_prefill_step
-    from . import hlo_analysis, roofline
+    from . import hlo_analysis
     from .cells import skip_reason
     from .mesh import axis_sizes, dp_axes_of, make_production_mesh
     from .specs import input_specs, opt_state_pspecs, opt_state_specs
@@ -113,7 +113,6 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir: str,
         cost = cost[0] if cost else {}
     hlo = compiled.as_text()
     stats = hlo_analysis.analyze(hlo)
-    roof = roofline.roofline(stats, model, shp, n_chips)
 
     rec = {
         "cell": cell_id, "status": "ok", "arch": arch, "shape": shape_name,
@@ -130,7 +129,6 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir: str,
         "cost_analysis_flops_unscaled": cost.get("flops"),
         "hlo_stats": {k: v for k, v in stats.items() if k != "trip_counts"},
         "trip_counts": stats["trip_counts"],
-        "roofline": roof,
         "overrides": overrides or {},
     }
     if save_hlo:
@@ -139,7 +137,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir: str,
     _write(out_dir, cell_id, rec)
     print(json.dumps({k: rec[k] for k in
                       ("cell", "status", "compile_s", "bytes_per_device",
-                       "bytes_per_device_tpu_est")} | {"roofline": roof}))
+                       "bytes_per_device_tpu_est")}))
     return rec
 
 

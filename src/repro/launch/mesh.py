@@ -14,8 +14,8 @@ from jax.sharding import AxisType
 
 
 def _auto(axes) -> tuple:
-    """Auto axes: the plan's collectives and gspmd's partitioner decide the
-    layouts (jax.make_mesh otherwise makes Explicit axes, which reject
+    """Auto axes: the plan's collectives and XLA's SPMD partitioner decide
+    the layouts (jax.make_mesh otherwise makes Explicit axes, which reject
     sharded contracting dimensions)."""
     return (AxisType.Auto,) * len(axes)
 

@@ -1,4 +1,4 @@
-"""Distributed (shard_map + gspmd) execution of compiled loop programs
+"""Distributed (shard_map) execution of compiled loop programs
 equals single-device execution — run in a subprocess with 8 forced host
 devices (the main test process must keep 1 device)."""
 import os
@@ -49,13 +49,12 @@ cases = {
 for name, ins in cases.items():
     fn = ALL[name]
     single = compile_program(fn).run(ins)
-    for mode in ("shardmap", "gspmd"):
-        dist = compile_distributed(fn, mesh, ("data",), mode=mode).run(ins)
-        for k in single:
-            a = np.asarray(dist[k], np.float64)
-            b = np.asarray(single[k], np.float64)
-            err = np.max(np.abs(a - b) / (np.abs(b) + 1.0))
-            assert err < 1e-4, (name, mode, k, err)
+    dist = compile_distributed(fn, mesh, ("data",)).run(ins)
+    for k in single:
+        a = np.asarray(dist[k], np.float64)
+        b = np.asarray(single[k], np.float64)
+        err = np.max(np.abs(a - b) / (np.abs(b) + 1.0))
+        assert err < 1e-4, (name, k, err)
 print("DIST_OK")
 """
 
@@ -93,23 +92,22 @@ cases = {
 for name, ins in cases.items():
     fn = ALL[name]
     single = compile_program(fn).run(ins)
-    for mode in ("shardmap", "gspmd"):
-        dp = compile_distributed(fn, mesh, ("data",), mode=mode)
-        # odd-length bags must SHARD (padded), not silently replicate
-        placed, limits, dense_limits = dp.place(ins)
-        bag = next(k for k, t in fn.program.params.items()
-                   if t.kind == "bag")
-        assert limits[bag] == n, (name, limits)
-        col = placed[bag][0]
-        assert col.shape[0] == 72
-        assert col.sharding.spec == PartitionSpec(("data",)), \
-            (name, col.sharding.spec)
-        dist = dp.run(ins)
-        for k in single:
-            a = np.asarray(dist[k], np.float64)
-            b = np.asarray(single[k], np.float64)
-            err = np.max(np.abs(a - b) / (np.abs(b) + 1.0))
-            assert err < 1e-4, (name, mode, k, err)
+    dp = compile_distributed(fn, mesh, ("data",))
+    # odd-length bags must SHARD (padded), not silently replicate
+    placed, limits, dense_limits = dp.place(ins)
+    bag = next(k for k, t in fn.program.params.items()
+               if t.kind == "bag")
+    assert limits[bag] == n, (name, limits)
+    col = placed[bag][0]
+    assert col.shape[0] == 72
+    assert col.sharding.spec == PartitionSpec(("data",)), \
+        (name, col.sharding.spec)
+    dist = dp.run(ins)
+    for k in single:
+        a = np.asarray(dist[k], np.float64)
+        b = np.asarray(single[k], np.float64)
+        err = np.max(np.abs(a - b) / (np.abs(b) + 1.0))
+        assert err < 1e-4, (name, k, err)
 print("ODD_OK")
 """
 
@@ -152,11 +150,10 @@ ins = dict(B=rng.standard_normal(nb), M=rng.standard_normal((nb, m)),
            R=np.zeros(m), m=m)
 single = cp.run(ins)
 mesh = make_test_mesh((8,), ("data",))
-for mode in ("shardmap", "gspmd"):
-    dist = compile_distributed(col_sums, mesh, ("data",), mode=mode).run(ins)
-    err = np.max(np.abs(np.asarray(dist["R"], np.float64)
-                        - np.asarray(single["R"], np.float64)))
-    assert err < 1e-4, (mode, err)
+dist = compile_distributed(col_sums, mesh, ("data",)).run(ins)
+err = np.max(np.abs(np.asarray(dist["R"], np.float64)
+                    - np.asarray(single["R"], np.float64)))
+assert err < 1e-4, err
 print("EINSUM_BAG_OK")
 """
 
@@ -189,14 +186,13 @@ rng = np.random.default_rng(17)
 def check(name, ins):
     fn = ALL[name]
     single = compile_program(fn).run(ins)
-    for mode in ("shardmap", "gspmd"):
-        dist = compile_distributed(fn, mesh, ("data",), mode=mode).run(ins)
-        for k in single:
-            a = np.asarray(dist[k], np.float64)
-            b = np.asarray(single[k], np.float64)
-            assert a.shape == b.shape, (name, mode, k, a.shape, b.shape)
-            err = np.max(np.abs(a - b) / (np.abs(b) + 1.0))
-            assert err < 1e-4, (name, mode, k, err)
+    dist = compile_distributed(fn, mesh, ("data",)).run(ins)
+    for k in single:
+        a = np.asarray(dist[k], np.float64)
+        b = np.asarray(single[k], np.float64)
+        assert a.shape == b.shape, (name, k, a.shape, b.shape)
+        err = np.max(np.abs(a - b) / (np.abs(b) + 1.0))
+        assert err < 1e-4, (name, k, err)
     return single
 
 
@@ -260,3 +256,16 @@ def test_dense_arrays_shard_not_replicate():
                        timeout=900)
     assert r.returncode == 0, r.stdout[-3000:] + r.stderr[-3000:]
     assert "DENSE_OK" in r.stdout
+
+
+def test_only_shardmap_mode_is_accepted():
+    from repro.core import compile_program
+    from repro.core.distributed import compile_distributed
+    from repro.core.programs import ALL
+    from repro.launch.mesh import make_test_mesh
+    mesh = make_test_mesh((1,), ("data",))
+    cp = compile_program(ALL["word_count"])
+    assert compile_distributed(cp, mesh, ("data",),
+                               mode="shardmap").mode == "shardmap"
+    with pytest.raises(ValueError, match="only 'shardmap'"):
+        compile_distributed(cp, mesh, ("data",), mode="gspmd")

@@ -29,9 +29,9 @@ def test_cost_model_decision_table_cpu():
         # (n, k, d, op)                      -> expected backend
         ((200_000, 1000, 1, "+"), "scatter"),   # large N: scatter wins
         ((8192, 128, 1, "+"), "scatter"),
-        ((4096, 16, 1, "+"), "onehot"),         # tiny K: one-hot dot wins
-        ((512, 8, 1, "+"), "onehot"),
-        ((200_000, 1000, 1, "min"), "scatter"),  # no onehot for min
+        ((4096, 16, 1, "+"), "scatter"),        # tiny K as well
+        ((512, 8, 1, "+"), "scatter"),
+        ((200_000, 1000, 1, "min"), "scatter"),
         ((65_536, 4096, 1, "*"), "scatter"),     # sort never wins on cpu
     ]
     for (n, k, d, op), want in table:
@@ -49,12 +49,28 @@ def test_cost_model_decision_table_tpu():
     assert big.backend == "pallas"
     small = sel.choose_segment(n=512, k=8, d=1, op="+", dtype="float32",
                                dest_dist="ONED_ROW")
-    assert small.backend == "onehot"
+    assert small.backend == "pallas"
     cpu = OpSelector(mode="cost", cache_path=None, platform="cpu")
     for n, k in [(512, 8), (8192, 128), (200_000, 1000)]:
         dec = cpu.choose_segment(n=n, k=k, d=1, op="+", dtype="float32",
                                  dest_dist="ONED_ROW")
         assert dec.backend != "pallas", (n, k, dec)
+
+
+@pytest.mark.parametrize("cell,n,k,want", [
+    ("graph500-s22.pagerank10", 128_310_114, 2_396_887, "sort"),
+    ("tpch-sf10.q18-groupby", 59_986_052, 15_000_000, "sort"),
+    ("tpch-sf10.q1-groupby", 59_986_052, 6, "pallas"),
+    # the per-chip share of the four-chip cell's fold
+    ("graph500-s25.pagerank10-mesh4", 261_802_476, 17_063_336, "sort")])
+def test_tpu_cost_row_picks_at_cell_shapes(cell, n, k, want):
+    # the backend each chip benchmark cell runs, pinned at its shape: a
+    # change to the candidate sets or the TPU cost row must not move it
+    sel = OpSelector(mode="cost", cache_path=None, platform="tpu")
+    for dest in ("REP", "ONED_ROW"):
+        dec = sel.choose_segment(n=n, k=k, d=1, op="+", dtype="float32",
+                                 dest_dist=dest)
+        assert (dec.backend, dec.source) == (want, "cost"), (cell, dest, dec)
 
 
 @pytest.mark.parametrize("dtype", ["int32", "uint32"])
@@ -94,11 +110,10 @@ def test_cost_model_refuses_a_platform_without_a_cost_row():
 
 
 def test_candidate_sets_respect_monoid():
-    assert "onehot" not in SEGMENT_CANDIDATES["min"]   # onehot only sums
-    assert "onehot" not in SEGMENT_CANDIDATES["*"]
     assert "pallas" not in SEGMENT_CANDIDATES["*"]
-    assert set(SEGMENT_CANDIDATES["+"]) == {"scatter", "sort", "onehot",
-                                            "pallas"}
+    assert set(SEGMENT_CANDIDATES["+"]) == {"scatter", "sort", "pallas"}
+    for op in ("min", "max"):
+        assert set(SEGMENT_CANDIDATES[op]) == {"scatter", "sort", "pallas"}
 
 
 def test_exchange_decision_table():
@@ -206,8 +221,7 @@ def test_autotune_compile_produces_identical_plan(tmp_path):
 # paper's drop semantics (negative and out-of-range keys)
 # ---------------------------------------------------------------------------
 
-_FORCE_MODES = ("force:scatter", "force:sort", "force:onehot",
-                "force:pallas")
+_FORCE_MODES = ("force:scatter", "force:sort", "force:pallas")
 
 
 @pytest.mark.parametrize("seed", [0, 1, 2])
@@ -244,8 +258,8 @@ def test_backend_equivalence_randomized(seed):
 
 def test_onehot_and_pallas_drop_nonfinite_values():
     # dropped rows may carry non-finite values (a condition guarding a
-    # division); the one-hot DOT paths must zero them — 0 × inf = NaN
-    # would otherwise contaminate every segment the matmul touches
+    # division); the Pallas kernel's one-hot dot must zero them — 0 × inf
+    # = NaN would otherwise contaminate every segment the matmul touches
     import jax.numpy as jnp
     from repro.core.frontend import bag, loop_program, map_
 
@@ -324,7 +338,7 @@ def test_auto_backend_selected_line_golden(tmp_path):
     # .repro_autotune.json (the cache overrides cost mode by design)
     cp = compile_program(ALL["group_by"],
                          autotune_cache=str(tmp_path / "cache.json"))
-    assert "backend=auto{scatter|sort|onehot|pallas}" in cp.explain()
+    assert "backend=auto{scatter|sort|pallas}" in cp.explain()
     assert "selected:" not in cp.explain()      # no run yet: no decision
     rng = np.random.default_rng(0)
     cp.run(dict(S=(rng.integers(0, 1000, 200_000).astype(float),
@@ -350,11 +364,11 @@ def test_cost_mode_honors_cache_override(tmp_path):
 
 
 def test_force_inapplicable_falls_through_to_model():
-    # force:onehot cannot apply to a min-group-by (onehot only sums):
-    # the selector must fall through to the cost model, not silently pin
-    # the first candidate
-    sel = OpSelector(mode="force:onehot", cache_path=None, platform="cpu")
-    dec = sel.choose_segment(n=8192, k=128, d=1, op="min", dtype="float32",
+    # force:pallas cannot apply to a *-group-by (the kernel has no
+    # product): the selector must fall through to the cost model, not
+    # silently pin the first candidate
+    sel = OpSelector(mode="force:pallas", cache_path=None, platform="cpu")
+    dec = sel.choose_segment(n=8192, k=128, d=1, op="*", dtype="float32",
                              dest_dist="REP")
     assert dec.backend == "scatter" and dec.source == "cost"
 

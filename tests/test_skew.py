@@ -142,8 +142,7 @@ def _reference(prog, ins):
     return np.asarray(cp.run(ins)["C"], np.float64)
 
 
-@pytest.mark.parametrize("backend", ["scatter", "sort", "onehot",
-                                     "pallas"])
+@pytest.mark.parametrize("backend", ["scatter", "sort", "pallas"])
 @pytest.mark.parametrize("salting", ["off", "force:4"])
 def test_degenerate_streams_equivalent(backend, salting):
     for stream, keys, vals in _streams():
